@@ -68,7 +68,7 @@ from repro.core.signatures import Signature, structural_key
 from repro.core.stats import KernelStats
 
 from .result import StudyResult
-from .scheduler import Executor, InProcessExecutor, Scheduler
+from .scheduler import Executor, ForkExecutor, InProcessExecutor, Scheduler
 from .session import AutotuneSession, run_payload
 from .transfer import StatisticsBank
 
@@ -306,7 +306,15 @@ class BackgroundTuner:
 
     def submit(self, key: str, session: AutotuneSession, *,
                tag: str = "tune") -> None:
-        job = (key, session, self._payload(session), tag)
+        executor = self.executor_factory()
+        if isinstance(executor, ForkExecutor) \
+                and not getattr(session.backend, "parallel_safe", True):
+            # a forked child of a process that holds the chip cannot reach
+            # it (the same guard as AutotuneSession._select_executor)
+            raise ValueError(
+                f"{type(session.backend).__name__} is not parallel_safe: "
+                f"it measures in this process, not on a ForkExecutor")
+        job = (key, session, self._payload(session), tag, executor)
         if self.synchronous:
             self._run(job)
             return
@@ -324,8 +332,7 @@ class BackgroundTuner:
             session.prior, collect=True, shared=False)
 
     def _run(self, job) -> None:
-        key, session, payload, tag = job
-        executor = self.executor_factory()
+        key, session, payload, tag, executor = job
 
         def runner(p: dict) -> dict:
             return run_payload(session.space, session.backend, p,

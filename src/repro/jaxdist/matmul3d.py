@@ -12,18 +12,21 @@ resharding of the operands' layouts.
 
 from __future__ import annotations
 
+import math
+from typing import Tuple
+
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro import compat
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
-def make_3d_mesh(c: int) -> Mesh:
-    """c x c x c mesh with axes (x, y, z) over c^3 devices."""
-    return make_mesh((c, c, c), ("x", "y", "z"),
-                     axis_types=(AxisType.Auto,) * 3)
+def make_3d_mesh(shape: Tuple[int, int, int]) -> Mesh:
+    """Mesh with axes (x, y, z) of the given shape over the first
+    prod(shape) devices.  A 4-chip host takes (2, 1, 2): the 'z' reduction
+    of ``matmul_3d`` then crosses chips."""
+    return jax.make_mesh(shape, ("x", "y", "z"),
+                         axis_types=(AxisType.Auto,) * 3,
+                         devices=jax.devices()[:math.prod(shape)])
 
 
 def matmul_3d(a, b, mesh: Mesh):
@@ -34,7 +37,7 @@ def matmul_3d(a, b, mesh: Mesh):
         c_part = jnp.dot(al, bl, preferred_element_type=jnp.float32)
         return jax.lax.psum(c_part, "z").astype(al.dtype)
 
-    fn = compat.shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P("x", "z"), P("z", "y")),
                        out_specs=P("x", "y"), check_vma=False)
     return fn(a, b)

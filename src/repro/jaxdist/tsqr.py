@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def tsqr(a, mesh: Mesh, axis: str = "x"):
     """a: (m, n) with m row-sharded over ``axis`` (m % p == 0, m/p >= n).
@@ -34,7 +32,7 @@ def tsqr(a, mesh: Mesh, axis: str = "x"):
         return q, r
 
     other = [ax for ax in mesh.axis_names if ax != axis]
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=P(axis, None),
         out_specs=(P(axis, None), P(*[None] * 2)),

@@ -2,7 +2,7 @@
 
 Each kernel ships three artifacts:
   <name>.py   pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target)
-  ops.py      jit'd dispatch wrappers (kernel on TPU / interpret elsewhere)
+  ops.py      dispatch wrappers (TPU, or the interpreter when asked)
   ref.py      pure-jnp oracles the tests assert against
 
 Kernels present:

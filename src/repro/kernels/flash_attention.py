@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiles import block
+
 _NEG_INF = -1e30
 
 
@@ -73,14 +75,14 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
     B, H, Sq, d = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     G = H // KVH
-    bq = min(bq, Sq)
-    while Sq % bq:
-        bq -= 1
-    bk = min(bk, Skv)
-    while Skv % bk:
-        bk -= 1
+    # query rows are independent, so a partial last q block is exact; a
+    # partial KV block would feed garbage into the softmax, so a KV length
+    # without such a divisor is taken whole
+    bq, bk = block(bq, Sq, 8), block(bk, Skv, 128)
+    if Skv % bk:
+        bk = Skv
     n_k = Skv // bk
-    grid = (B, H, Sq // bq, n_k)
+    grid = (B, H, pl.cdiv(Sq, bq), n_k)
     scale = 1.0 / math.sqrt(d)
     return pl.pallas_call(
         functools.partial(_flash_kernel, n_k=n_k, bq=bq, bk=bk,

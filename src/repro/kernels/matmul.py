@@ -4,7 +4,8 @@ Tiling: C (M,N) is produced in (bm, bn) VMEM tiles; the K dimension is the
 innermost grid axis so each (i, j) tile accumulates over K-steps into a VMEM
 scratch accumulator in f32 (MXU-native accumulation), writing C once at the
 final K step.  Tile sizes default to 128/256 multiples — MXU systolic array
-alignment (128x128) and lane width (128) — and are clamped to the problem.
+alignment (128x128) and lane width (128) — and are clamped to the problem
+(``tiles.block``).
 
 Grid iteration order (k innermost) keeps the C tile resident in VMEM across
 K steps: A and B tiles stream HBM->VMEM, C writes once — the standard
@@ -19,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiles import block
 
 
 def _matmul_kernel(a_ref, b_ref, c_ref, acc_ref, *, n_k: int):
@@ -36,13 +39,6 @@ def _matmul_kernel(a_ref, b_ref, c_ref, acc_ref, *, n_k: int):
         c_ref[...] = acc_ref[...].astype(c_ref.dtype)
 
 
-def _clamp(b, n):
-    b = min(b, n)
-    while n % b:
-        b -= 1
-    return b
-
-
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def matmul_pallas(a, b, *, bm: int = 256, bn: int = 256, bk: int = 512,
                   interpret: bool = False):
@@ -50,9 +46,14 @@ def matmul_pallas(a, b, *, bm: int = 256, bn: int = 256, bk: int = 512,
     M, K = a.shape
     K2, N = b.shape
     assert K == K2, (a.shape, b.shape)
-    bm, bn, bk = _clamp(bm, M), _clamp(bn, N), _clamp(bk, K)
+    # a partial edge block in M or N only computes rows/columns that are
+    # never written back; one in K would add garbage into the accumulator,
+    # so K without such a divisor is taken whole
+    bm, bn, bk = block(bm, M, 8), block(bn, N, 128), block(bk, K, 128)
+    if K % bk:
+        bk = K
     n_k = K // bk
-    grid = (M // bm, N // bn, n_k)
+    grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), n_k)
     return pl.pallas_call(
         functools.partial(_matmul_kernel, n_k=n_k),
         grid=grid,

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tiles import block
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -33,12 +35,11 @@ def rmsnorm_pallas(x, w, *, eps: float = 1e-5, block_rows: int = 256,
     D = x.shape[-1]
     xf = x.reshape(-1, D)
     R = xf.shape[0]
-    br = min(block_rows, R)
-    while R % br:
-        br -= 1
+    # rows are independent, so a partial last block is exact
+    br = block(block_rows, R, 8)
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
-        grid=(R // br,),
+        grid=(pl.cdiv(R, br),),
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
             pl.BlockSpec((D,), lambda i: (0,)),

@@ -14,16 +14,14 @@ collectives cross the inter-pod links.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1, *, pod: int = 0) -> Mesh:
@@ -37,5 +35,5 @@ def make_host_mesh(model: int = 1, *, pod: int = 0) -> Mesh:
         assert n % model == 0
         shape = (n // model, model)
         axes = ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -5,6 +5,7 @@
 
 Spins up the slot-based engine on a (reduced) model with random weights and
 replays a batch of synthetic prompts, reporting aggregate decode throughput.
+``main`` returns the engine, which holds the results, and the requests.
 
 With ``--daemon``, instead drives simulated traffic through the always-on
 tuning daemon (``repro.serve.tuner.run_daemon_demo``): shape misses open
@@ -22,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.models.model import Model, ModelKnobs
 from repro.serve.engine import Engine, Request, ServeConfig
 
@@ -36,6 +38,9 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--prompt-len", type=int, nargs=2, default=(4, 31),
+                    metavar=("LO", "HI"),
+                    help="prompt lengths are drawn from --seed in [LO, HI]")
     ap.add_argument("--daemon", action="store_true",
                     help="run the always-on tuning daemon demo instead")
     ap.add_argument("--rounds", type=int, default=4,
@@ -48,6 +53,7 @@ def main(argv=None):
 
     if args.daemon:
         return _daemon_demo(args)
+    enable_compilation_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     model = Model(cfg, ModelKnobs(kv_chunk=32, ssm_chunk=16))
@@ -57,23 +63,26 @@ def main(argv=None):
         max_new_tokens=args.max_new, temperature=args.temperature,
         seed=args.seed))
     rng = np.random.default_rng(args.seed)
+    reqs = []
     for uid in range(args.requests):
-        n = int(rng.integers(4, 32))
+        n = int(rng.integers(args.prompt_len[0], args.prompt_len[1] + 1))
         shape = (n, cfg.n_codebooks) if cfg.n_codebooks else (n,)
-        eng.submit(Request(uid, rng.integers(0, cfg.vocab, size=shape)
-                           .astype(np.int32)))
-    t0 = time.time()
+        reqs.append(Request(uid, rng.integers(0, cfg.vocab, size=shape)
+                            .astype(np.int32)))
+        eng.submit(reqs[-1])
+    t0 = time.perf_counter()
     steps = 0
     while eng.queue or eng.active.any():
         eng.step()
         steps += 1
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     toks = sum(len(r.tokens) for r in eng.results.values())
-    print(f"{args.requests} requests, {toks} tokens in {dt:.2f}s "
-          f"({toks / dt:.1f} tok/s, {steps} engine steps)")
+    print(f"{args.requests} requests, {toks} tokens, {steps} engine steps; "
+          f"host-clock timing, not a benchmark: {dt:.2f} s with compiles "
+          f"({toks / dt:.1f} tok/s)")
     for uid in sorted(eng.results)[:4]:
         print(f"  req {uid}: {eng.results[uid].tokens[:12]} ...")
-    return eng.results
+    return eng, reqs
 
 
 def _daemon_demo(args) -> dict:

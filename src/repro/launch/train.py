@@ -6,7 +6,8 @@
 Runs the full stack: config -> model -> sharded train step (when a mesh is
 requested) -> synthetic data pipeline -> checkpoint/restart.  Auto-resumes
 from the latest checkpoint in --ckpt-dir (fault tolerance: kill it at any
-step and rerun the same command).
+step and rerun the same command).  ``main`` returns the final params, the
+per-step losses and the host-clock init, compile and step times.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ import numpy as np
 
 from repro.configs import SHAPES, get_config
 from repro.configs.base import Shape
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.models.model import Model, ModelKnobs
 from repro.parallel.sharding import make_rules
 from repro.train import checkpoint as ckpt
@@ -47,6 +49,8 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compilation_cache()
+    t_start = time.perf_counter()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     shape = Shape("cli", args.seq, args.batch, "train")
@@ -89,22 +93,34 @@ def main(argv=None):
 
     it = batch_iterator(cfg, shape, DataConfig(seed=args.seed),
                         start_step=start)
-    t0 = time.time()
+    init_s = time.perf_counter() - t_start
+    losses, step_s, compile_s = [], [], 0.0
     for i in range(start, args.steps):
         host_batch = next(it)
         batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+        if i == start:      # compile once, outside the step timings
+            t0 = time.perf_counter()
+            step_fn = step_fn.lower(params, opt_state, batch).compile()
+            compile_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
         params, opt_state, metrics = step_fn(params, opt_state, batch)
+        losses.append(float(metrics["loss"]))      # waits for the step
+        step_s.append(time.perf_counter() - t0)
         if (i + 1) % args.log_every == 0 or i == start:
-            loss = float(metrics["loss"])
-            dt = (time.time() - t0) / max(i + 1 - start, 1)
-            print(f"step {i + 1:5d}  loss {loss:.4f}  "
-                  f"gnorm {float(metrics['grad_norm']):.3f}  "
-                  f"{dt * 1e3:.0f} ms/step")
+            print(f"step {i + 1:5d}  loss {losses[-1]:.4f}  "
+                  f"gnorm {float(metrics['grad_norm']):.3f}")
         if args.ckpt_dir and (i + 1) % args.ckpt_every == 0:
             ckpt.save(args.ckpt_dir, i + 1,
                       {"params": params, "opt": opt_state}, keep=3)
-    print("done:", args.steps, "steps")
-    return params
+    # the first step also moves data and warms up: steady excludes it
+    steady = step_s[1:] or step_s
+    steady_s = sum(steady) / len(steady) if steady else float("nan")
+    print(f"done: {args.steps} steps; host-clock timing, not a benchmark: "
+          f"init {init_s:.2f} s, compile {compile_s:.2f} s, first step "
+          f"{step_s[0] if step_s else float('nan'):.3f} s, steady "
+          f"{steady_s * 1e3:.1f} ms/step")
+    return {"params": params, "losses": losses, "init_s": init_s,
+            "compile_s": compile_s, "step_s": step_s, "steady_s": steady_s}
 
 
 if __name__ == "__main__":

@@ -82,15 +82,17 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal=True,
         Skv, KVH, dk_, dv = kv_expand.shape_info  # type: ignore[attr-defined]
     G = H // KVH
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(dk)
-    n_chunks = max(Skv // kv_chunk, 1)
-    chunk = Skv // n_chunks
-    assert chunk * n_chunks == Skv, (Skv, kv_chunk)
+    chunk = min(kv_chunk, Skv)
+    n_chunks = -(-Skv // chunk)
+    ragged = Skv % chunk != 0
 
     qg = q.reshape(B, Sq, KVH, G, dk)
 
     def body(carry, i):
         acc, m, l = carry
-        s0 = i * chunk
+        # when no chunk divides Skv, the last chunk is shifted back to end
+        # at Skv, and the keys it shares with the chunk before are masked
+        s0 = jnp.minimum(i * chunk, Skv - chunk) if ragged else i * chunk
         if kv_expand is None:
             kc = lax.dynamic_slice_in_dim(k, s0, chunk, axis=1)
             vc = lax.dynamic_slice_in_dim(v, s0, chunk, axis=1)
@@ -100,8 +102,13 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal=True,
         # scores: (B, KVH, G, Sq, C)
         s = jnp.einsum("bqhgd,bchd->bhgqc", qg, kc,
                        preferred_element_type=jnp.float32) * scale
+        mask = None
         if causal:
             mask = q_positions[:, None] >= pos_c[None, :]
+        if ragged:
+            fresh = (s0 + jnp.arange(chunk) >= i * chunk)[None, :]
+            mask = fresh if mask is None else mask & fresh
+        if mask is not None:
             s = jnp.where(mask[None, None, None], s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])

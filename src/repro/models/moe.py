@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.parallel.sharding import annotate, current_rules
 
 
@@ -246,7 +244,7 @@ def _ep_dispatch(p, xf, moe, rules):
 
     in_specs = (tok_spec, P(None, None),
                 w_specs["w_gate"], w_specs["w_up"], w_specs["w_down"])
-    fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=tok_spec, check_vma=False)
     return fn(xf, p["router"].astype(xf.dtype), p["w_gate"], p["w_up"],
               p["w_down"])

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.parallel.sharding import annotate, current_rules, is_axes_leaf
 from .layers import rms_norm
 
@@ -53,8 +52,8 @@ def _manual_scan(scan_fn, arg_axes, out_axes, args):
     out_shapes = _jax.eval_shape(scan_fn, *args)
     out_specs = _jax.tree.map(spec_of, out_axes, out_shapes,
                               is_leaf=is_axes_leaf)
-    fn = compat.shard_map(scan_fn, mesh=rules.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(scan_fn, mesh=rules.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(*args)
 
 

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 BLOCK = 256
 
 
@@ -149,6 +147,6 @@ def ring_allreduce_int8(stacked: jnp.ndarray, mesh: Mesh, axis: str):
         return flat_out.reshape(x.shape).astype(x.dtype)[None]
 
     other_none = [None] * (stacked.ndim - 1)
-    return compat.shard_map(
+    return jax.shard_map(
         ring, mesh=mesh, in_specs=P(axis, *other_none),
         out_specs=P(axis, *other_none), check_vma=False)(stacked)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.models import layers as ML
 from repro.models.model import Model
 from repro.parallel.sharding import ShardingRules, axis_rules
@@ -140,7 +138,7 @@ def pipeline_loss(model: Model, rules: ShardingRules, params, batch, *,
         jax.tree.map(lambda a: P(), other),
         jax.tree.map(lambda a: P(), mbs),
     )
-    fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=P(), check_vma=False,
                        axis_names={"pod"})
     return fn(stage_stacks, other, mbs)

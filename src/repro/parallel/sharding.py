@@ -25,8 +25,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import AxisType, get_abstract_mesh
-
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 
@@ -228,14 +226,8 @@ def annotate(x, *logical: Optional[str]):
     if rules is None or rules.mesh is None:
         return x
     spec = rules.spec(*logical, dims=x.shape)
-    ctx = get_abstract_mesh()
-    try:
-        manual = ctx is not None and getattr(ctx, "shape_tuple", ()) and \
-            any(t == AxisType.Manual
-                for t in getattr(ctx, "axis_types", ()))
-    except Exception:
-        manual = False
-    if manual:
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx.manual_axes:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(ctx, spec))
     return jax.lax.with_sharding_constraint(

@@ -1,5 +1,6 @@
 """Measured-mode LM autotuning study (the paper's technique on our own
-framework, real wall-clock, reduced architectures on CPU).
+framework, real wall-clock; reduced architectures, or published widths on
+the chip).
 
 A *configuration* is a ``StepKnobs`` point (grad accumulation x remat x
 attention/ssm chunking x MoE dispatch).  A configuration's step is
@@ -72,11 +73,12 @@ def _block_params(model: Model, params, pos: int, period: int):
 
 
 class LMStudy:
-    """Benchmarks StepKnobs configurations for one reduced arch."""
+    """Benchmarks StepKnobs configurations for one arch: its reduced
+    config by default, its published widths with ``reduced=False``."""
 
-    def __init__(self, arch: str, *, batch: int = 2, seq: int = 32,
-                 seed: int = 0):
-        self.cfg = get_config(arch, reduced=True)
+    def __init__(self, arch: str, *, reduced: bool = True, batch: int = 2,
+                 seq: int = 32, seed: int = 0):
+        self.cfg = get_config(arch, reduced=reduced)
         self.batch, self.seq = batch, seq
         key = jax.random.PRNGKey(seed)
         self.params = init_params(self.cfg, key)

@@ -5,9 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.launch.hlo_analysis import (collective_stats, group_size,
                                        parse_collective_line)
@@ -20,7 +18,7 @@ pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
 
 
 def _mesh():
-    return make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
 
 
 def test_jaxpr_cost_exact_matmul():

@@ -23,7 +23,8 @@ from repro.api import (AutotuneSession, ConfigPoint, DaemonConfig,
                        ForkExecutor, InProcessExecutor, RESET_POLICY,
                        SearchSpace, StatisticsBank, TuningDaemon,
                        WallClockBackend, fork_available)
-from repro.api.daemon import DriftDetector, FleetStore, TUNED, TUNING
+from repro.api.daemon import (BackgroundTuner, DriftDetector, FleetStore,
+                              TUNED, TUNING)
 from repro.core.signatures import comp_sig, structural_key
 from repro.core.stats import KernelStats
 from repro.serve.engine import bucket_length
@@ -38,6 +39,13 @@ def _stats_of(xs) -> KernelStats:
 
 
 # ------------------------------------------------- synthetic study provider
+
+class VirtualClockBackend(WallClockBackend):
+    """The wall-clock protocol over virtual-clock thunks: they touch no
+    device, so unlike real wall-clock kernels they may run in a fork."""
+
+    parallel_safe = True
+
 
 class SyntheticProvider:
     """Two-config studies over fake kernels with dict-driven costs.
@@ -86,7 +94,7 @@ class SyntheticProvider:
 
         return AutotuneSession(
             self._space(meta["shape"]),
-            backend=WallClockBackend(kernels_of, clock=clock),
+            backend=VirtualClockBackend(kernels_of, clock=clock),
             policy="eager", tolerance=0.5, min_samples=2,
             trials=self.trials, prior=prior, prior_discount=1.0,
             collect_stats=True)
@@ -272,6 +280,21 @@ def test_fork_background_retune_bit_identical_to_inprocess():
     inproc = flow(InProcessExecutor)
     forked = flow(lambda: ForkExecutor(1))
     assert forked == inproc
+
+
+@pytest.mark.skipif(not fork_available(), reason="no os.fork")
+def test_background_tuner_refuses_fork_for_serial_backend():
+    """A backend that is not parallel_safe (real wall-clock kernels, which
+    hold the chip in this process) is never handed to a fork pool."""
+    clock = VirtualClock()
+    session = SyntheticProvider(clock, {}).session_for(
+        "k", {"shape": "s1"}, None)
+    session.backend = WallClockBackend(session.backend.kernels_of,
+                                       clock=clock)
+    tuner = BackgroundTuner(executor_factory=lambda: ForkExecutor(1),
+                            synchronous=True)
+    with pytest.raises(ValueError, match="parallel_safe"):
+        tuner.submit("k", session)
 
 
 # --------------------------------------------------- satellite: age discount

@@ -161,3 +161,40 @@ def test_remat_matches_no_remat():
     from dataclasses import replace
     l2 = Model(cfg, replace(KNOBS, remat="none")).loss(p, batch)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
+
+
+@pytest.mark.parametrize("sq,skv,chunk,causal", [
+    (300, 300, 32, True),       # prompt length no chunk divides
+    (20, 300, 32, True),        # suffix queries against a ragged KV
+    (37, 100, 64, False),       # one full chunk + a shifted partial one
+    (64, 64, 16, True)])        # divisible: the unshifted path
+def test_chunked_attention_ragged_matches_naive(sq, skv, chunk, causal):
+    from repro.kernels.ref import flash_attention_ref
+    from repro.models.layers import chunked_attention
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(k1, (2, sq, 4, 16))
+    k = jax.random.normal(k2, (2, skv, 2, 16))
+    v = jax.random.normal(k3, (2, skv, 2, 16))
+    got = chunked_attention(q, k, v, q_positions=jnp.arange(skv - sq, skv),
+                            kv_positions=jnp.arange(skv), causal=causal,
+                            kv_chunk=chunk)
+    want = flash_attention_ref(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "deepseek-v2-236b"])
+def test_ragged_kv_chunk_forward_matches_single_chunk(arch):
+    """GQA and MLA (lazy per-chunk K/V) at a length no kv_chunk divides
+    give the logits of one whole-sequence chunk."""
+    cfg = get_config(arch, reduced=True)
+    if cfg.moe is not None:
+        from dataclasses import replace as drep
+        cfg = drep(cfg, moe=drep(cfg.moe, capacity_factor=64.0))
+    batch = make_batch(cfg, 2, 40, jax.random.PRNGKey(4))
+    params = Model(cfg, KNOBS).init(jax.random.PRNGKey(5))
+    ragged = Model(cfg, ModelKnobs(kv_chunk=16, ssm_chunk=8))
+    whole = Model(cfg, ModelKnobs(kv_chunk=64, ssm_chunk=8))
+    np.testing.assert_allclose(np.asarray(ragged.forward(params, batch)),
+                               np.asarray(whole.forward(params, batch)),
+                               rtol=2e-4, atol=2e-4)

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
@@ -19,25 +19,6 @@ from repro.train.step import TrainConfig, make_train_step
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
                                 reason="needs 8 virtual devices")
-
-# jax 0.4.x lowering gaps, version-gated via the compat shim (see ROADMAP
-# "jax 0.4.x gaps": revisit when the container jax is bumped, or add a
-# ppermute-based fallback lowering).  The skip reasons below name the
-# concrete failure so the skip report points at the ROADMAP item.
-from repro.compat import HAS_AXIS_TYPES  # noqa: E402
-
-skip_partial_manual = pytest.mark.skipif(
-    not HAS_AXIS_TYPES,
-    reason="jax 0.4.37 partial-manual shard_map gap (ROADMAP 'jax 0.4.x "
-           "gaps'): shard_map over an axis_names subset lowers axis_index "
-           "to PartitionId, which XLA SPMD rejects — requires jax >= 0.5")
-
-skip_cholesky3d_miscompile = pytest.mark.skipif(
-    not HAS_AXIS_TYPES,
-    reason="jax 0.4.37 recursive-shard_map miscompile (ROADMAP 'jax 0.4.x "
-           "gaps'): recursive composition of manual regions under "
-           "re-sharding constraints miscompiles cholesky3d on 0.4.x SPMD "
-           "— requires jax >= 0.5")
 
 
 def test_rules_spec_dedup_and_fallback():
@@ -164,7 +145,6 @@ def test_grad_accum_invariance():
                                    rtol=5e-3, atol=1e-4)
 
 
-@skip_partial_manual
 def test_pipeline_parallel_matches_reference():
     """GPipe-style pipeline over 'pod': loss and grads match the plain
     model (exact schedule equivalence through ppermute transposes)."""
@@ -193,9 +173,7 @@ def test_pipeline_parallel_matches_reference():
 
 def test_int8_ring_allreduce():
     from repro.parallel.compression import ring_allreduce_int8
-    mesh = make_host_mesh(model=1)        # (8,) pure data... (8,1)
-    from repro.compat import AxisType, make_mesh
-    mesh = make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     x = np.random.default_rng(0).standard_normal((8, 777)) \
         .astype(np.float32)
     xs = jax.device_put(x, NamedSharding(mesh, P("data")))
@@ -224,7 +202,7 @@ def test_error_feedback_reduces_bias():
 
 def test_jaxdist_algorithms():
     from repro.jaxdist import make_3d_mesh, matmul_3d, tsqr
-    mesh = make_3d_mesh(2)
+    mesh = make_3d_mesh((2, 2, 2))
     rng = np.random.default_rng(0)
     A = rng.standard_normal((32, 64)).astype(np.float32)
     B = rng.standard_normal((64, 16)).astype(np.float32)
@@ -242,10 +220,9 @@ def test_jaxdist_algorithms():
                                np.eye(8), atol=1e-4)
 
 
-@skip_cholesky3d_miscompile
 def test_jaxdist_cholesky3d():
     from repro.jaxdist import cholesky_3d, make_3d_mesh
-    mesh = make_3d_mesh(2)
+    mesh = make_3d_mesh((2, 2, 2))
     rng = np.random.default_rng(0)
     n = 32
     M = rng.standard_normal((n, n)).astype(np.float32)
