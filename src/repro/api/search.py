@@ -31,6 +31,7 @@ import math
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.policies import Policy
 from repro.core.stats import t_quantile_975
@@ -47,7 +48,8 @@ SEARCHES = ("exhaustive", "racing", "model_guided")
 
 def measure_config(run: "BackendRun", point: ConfigPoint, policy: Policy, *,
                    trials: int = 3) -> ConfigRecord:
-    """The paper's per-configuration measurement sequence."""
+    """The paper's per-configuration measurement sequence; the record is
+    built under the profiler span ``tuner.bookkeeping``."""
     ref = run.run_reference(point)
     full_time = ref.time
 
@@ -63,19 +65,20 @@ def measure_config(run: "BackendRun", point: ConfigPoint, policy: Policy, *,
         selective_cost += last.cost
         predictions.append(last.predicted)
 
-    predicted = predictions[-1]
-    rel_error = (abs(predicted - full_time) / full_time
-                 if full_time > 0 else 0.0)
-    comp_error = (abs(last.comp - ref.comp) / ref.comp
-                  if ref.comp > 0 else 0.0)
-    extra = dict(ref.extra)
-    extra.update(last.extra)
-    return ConfigRecord(
-        name=point.name, params=point.params, full_time=full_time,
-        predicted=predicted, rel_error=rel_error, comp_error=comp_error,
-        selective_cost=selective_cost, full_cost=full_time * trials,
-        executed=last.executed, skipped=last.skipped,
-        predictions=predictions, extra=extra)
+    with TraceAnnotation("tuner.bookkeeping"):
+        predicted = predictions[-1]
+        rel_error = (abs(predicted - full_time) / full_time
+                     if full_time > 0 else 0.0)
+        comp_error = (abs(last.comp - ref.comp) / ref.comp
+                      if ref.comp > 0 else 0.0)
+        extra = dict(ref.extra)
+        extra.update(last.extra)
+        return ConfigRecord(
+            name=point.name, params=point.params, full_time=full_time,
+            predicted=predicted, rel_error=rel_error, comp_error=comp_error,
+            selective_cost=selective_cost, full_cost=full_time * trials,
+            executed=last.executed, skipped=last.skipped,
+            predictions=predictions, extra=extra)
 
 
 def exhaustive(run: "BackendRun", space: SearchSpace, policy: Policy, *,
@@ -94,11 +97,13 @@ def exhaustive(run: "BackendRun", space: SearchSpace, policy: Policy, *,
         if i < len(records):
             continue
         if i > 0 and reset:
-            run.reset_models()
+            with TraceAnnotation("tuner.bookkeeping"):
+                run.reset_models()
         rec = measure_config(run, point, policy, trials=trials)
         records.append(rec)
         if on_record is not None:
-            on_record(rec)
+            with TraceAnnotation("tuner.bookkeeping"):
+                on_record(rec)
     return records, {}
 
 

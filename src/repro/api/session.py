@@ -65,6 +65,8 @@ import zlib
 from dataclasses import asdict, replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.policies import Policy, policy as make_policy
 
 from . import search as _search
@@ -191,76 +193,78 @@ class AutotuneSession:
             prior = self.prior
         if collect is None:
             collect = self.collect_stats
-        t0 = time.time()
-        run = self.backend.open(self.space, pol, seed=seed,
-                                allocation=allocation, prior=prior)
-        driver = _DRIVERS[self.search]
-        opts = dict(self.search_options)
-        key = self._key(pol, seed, allocation, prior=prior,
-                        collect=collect, shared=shared)
-        start = None
-        if checkpoint is not None and not shared \
-                and self.search == "exhaustive" \
-                and self.space.should_reset(pol):
-            # per-configuration journaling is protocol-safe only when
-            # statistics reset between configurations: a fresh backend at
-            # point k is then in the same state as one that measured
-            # points 0..k-1 — up to the backend's carry state (the sim
-            # RNG stream), journaled with every record and restored here
-            # (anything else resumes whole studies only).  Mid-sweep-shared
-            # tasks never journal partial records: a re-dispatched task may
-            # run under a different evolved prior than the killed one.
-            start, carry = checkpoint.partial(key)
-            if start:
-                run.restore_carry(carry)
-            opts["start_records"] = start
-            opts["on_record"] = lambda rec: checkpoint.add_record(
-                key, rec, run.carry_state())
-        if self.search == "model_guided":
-            if prior is not None and "banks" not in opts \
-                    and "model" not in opts:
-                # the seeded prior doubles as the candidate model unless
-                # the caller supplied explicit banks — mid-sweep shared
-                # statistics thereby sharpen later tasks' samplers, not
-                # just their skip regimes
-                opts["banks"] = [prior.to_json()]
-            if checkpoint is not None and not shared:
-                # the candidate selection (survivor set + post-selection
-                # sampler RNG) is journaled so a killed-and-resumed study
-                # re-races the same survivors without re-consuming sampler
-                # draws — bit-identical to the uninterrupted driver
-                st = checkpoint.search_state(key)
-                if st is not None:
-                    opts["start_state"] = st
-                opts["on_state"] = \
-                    lambda s: checkpoint.add_search_state(key, s)
+        with TraceAnnotation("tuner.bookkeeping"):
+            t0 = time.time()
+            run = self.backend.open(self.space, pol, seed=seed,
+                                    allocation=allocation, prior=prior)
+            driver = _DRIVERS[self.search]
+            opts = dict(self.search_options)
+            key = self._key(pol, seed, allocation, prior=prior,
+                            collect=collect, shared=shared)
+            start = None
+            if checkpoint is not None and not shared \
+                    and self.search == "exhaustive" \
+                    and self.space.should_reset(pol):
+                # per-configuration journaling is protocol-safe only when
+                # statistics reset between configurations: a fresh backend at
+                # point k is then in the same state as one that measured
+                # points 0..k-1 — up to the backend's carry state (the sim
+                # RNG stream), journaled with every record and restored here
+                # (anything else resumes whole studies only).  Mid-sweep-shared
+                # tasks never journal partial records: a re-dispatched task may
+                # run under a different evolved prior than the killed one.
+                start, carry = checkpoint.partial(key)
+                if start:
+                    run.restore_carry(carry)
+                opts["start_records"] = start
+                opts["on_record"] = lambda rec: checkpoint.add_record(
+                    key, rec, run.carry_state())
+            if self.search == "model_guided":
+                if prior is not None and "banks" not in opts \
+                        and "model" not in opts:
+                    # the seeded prior doubles as the candidate model unless
+                    # the caller supplied explicit banks — mid-sweep shared
+                    # statistics thereby sharpen later tasks' samplers, not
+                    # just their skip regimes
+                    opts["banks"] = [prior.to_json()]
+                if checkpoint is not None and not shared:
+                    # the candidate selection (survivor set + post-selection
+                    # sampler RNG) is journaled so a killed-and-resumed study
+                    # re-races the same survivors without re-consuming sampler
+                    # draws — bit-identical to the uninterrupted driver
+                    st = checkpoint.search_state(key)
+                    if st is not None:
+                        opts["start_state"] = st
+                    opts["on_state"] = \
+                        lambda s: checkpoint.add_search_state(key, s)
         records, extra = driver(run, self.space, pol, trials=self.trials,
                                 **opts)
-        if collect and not start:
-            # configurations replayed from a checkpoint journal never fed
-            # this run's models, so a resumed study cannot export the full
-            # posterior — omit the bank rather than present a partial one
-            # (resume the study without collect_stats, or re-run cold, to
-            # obtain a complete bank)
-            bank = run.export_stats()
-            if bank is not None:
+        with TraceAnnotation("tuner.bookkeeping"):
+            if collect and not start:
+                # configurations replayed from a checkpoint journal never fed
+                # this run's models, so a resumed study cannot export the full
+                # posterior — omit the bank rather than present a partial one
+                # (resume the study without collect_stats, or re-run cold, to
+                # obtain a complete bank)
+                bank = run.export_stats()
+                if bank is not None:
+                    extra = dict(extra)
+                    extra["kernel_stats"] = bank
+            cache_info = run.cache_info()
+            if cache_info is not None:
+                # program-cache provenance: per-point structural fingerprints
+                # plus this task's hit/miss/recording counters, so the nightly
+                # drift gate can attribute changes to code vs cached artifact
                 extra = dict(extra)
-                extra["kernel_stats"] = bank
-        cache_info = run.cache_info()
-        if cache_info is not None:
-            # program-cache provenance: per-point structural fingerprints
-            # plus this task's hit/miss/recording counters, so the nightly
-            # drift gate can attribute changes to code vs cached artifact
-            extra = dict(extra)
-            extra["program_cache"] = cache_info
-        result = StudyResult(
-            study=self.space.name, policy=pol.name,
-            tolerance=pol.tolerance, records=records,
-            full_tuning_time=sum(r.full_cost for r in records),
-            selective_tuning_time=sum(r.selective_cost for r in records),
-            backend=self.backend.name, search=self.search, seed=seed,
-            allocation=allocation, wall_s=round(time.time() - t0, 3),
-            extra=extra)
+                extra["program_cache"] = cache_info
+            result = StudyResult(
+                study=self.space.name, policy=pol.name,
+                tolerance=pol.tolerance, records=records,
+                full_tuning_time=sum(r.full_cost for r in records),
+                selective_tuning_time=sum(r.selective_cost for r in records),
+                backend=self.backend.name, search=self.search, seed=seed,
+                allocation=allocation, wall_s=round(time.time() - t0, 3),
+                extra=extra)
         return result
 
     def run(self, *, checkpoint: Optional[str] = None) -> StudyResult:

@@ -10,6 +10,12 @@ Slot-based continuous batching over a fixed-capacity decode batch:
 
 The decode step is jitted once per (batch capacity, s_max); prefill is
 jitted per prompt-length bucket.  Sampling: greedy or temperature.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, recorded only while a
+trace is active): once per admitted request ``engine.prefill`` (the
+call), ``engine.splice`` and ``engine.first_token`` (the prefill's logits
+pulled to the host and sampled); once per step ``engine.decode`` (the
+call and its logits pulled to the host) and ``engine.sample``.
 """
 
 from __future__ import annotations
@@ -21,6 +27,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models.model import Model, ModelKnobs
@@ -125,23 +132,27 @@ class Engine:
             if not self.queue:
                 break
             req = self.queue.pop(0)
-            S_p = self._bucket(len(req.tokens))
-            toks = np.zeros((1, S_p) + self._tok_trailing(), np.int32)
-            toks[0, :len(req.tokens)] = req.tokens
-            fn = self._prefill_cache.get(S_p)
-            if fn is None:
-                fn = jax.jit(lambda p, b, at: self._prefill_fn(
-                    p, b, self.sc.s_max, at))
-                self._prefill_cache[S_p] = fn
-            at = jnp.asarray([len(req.tokens) - 1], jnp.int32)
-            logits, cache1, _ = fn(self.params,
-                                   {"tokens": jnp.asarray(toks)}, at)
-            # splice the single-request cache into slot `slot`
-            self.cache = jax.tree.map(
-                lambda big, one: jax.lax.dynamic_update_slice_in_dim(
-                    big, one.astype(big.dtype), int(slot), axis=1),
-                self.cache, cache1)
-            tok0 = self._sample(np.asarray(logits)[0])
+            with TraceAnnotation("engine.prefill"):
+                S_p = self._bucket(len(req.tokens))
+                toks = np.zeros((1, S_p) + self._tok_trailing(), np.int32)
+                toks[0, :len(req.tokens)] = req.tokens
+                fn = self._prefill_cache.get(S_p)
+                if fn is None:
+                    fn = jax.jit(lambda p, b, at: self._prefill_fn(
+                        p, b, self.sc.s_max, at))
+                    self._prefill_cache[S_p] = fn
+                at = jnp.asarray([len(req.tokens) - 1], jnp.int32)
+                logits, cache1, _ = fn(self.params,
+                                       {"tokens": jnp.asarray(toks)}, at)
+            with TraceAnnotation("engine.splice"):
+                # splice the single-request cache into slot `slot`; its
+                # dispatch overlaps the prefill on the device
+                self.cache = jax.tree.map(
+                    lambda big, one: jax.lax.dynamic_update_slice_in_dim(
+                        big, one.astype(big.dtype), int(slot), axis=1),
+                    self.cache, cache1)
+            with TraceAnnotation("engine.first_token"):
+                tok0 = self._sample(np.asarray(logits)[0])
             self.last_token[slot] = tok0
             self.lengths[slot] = len(req.tokens)
             # the prefill-sampled token is the first generated token
@@ -170,25 +181,27 @@ class Engine:
         self._admit()
         if not self.active.any():
             return 0
-        t = jnp.asarray(self.lengths.astype(np.int32))
-        logits, self.cache = self._decode(
-            self.params, self.cache, t, jnp.asarray(self.last_token))
-        logits = np.asarray(logits)
-        for slot in np.nonzero(self.active)[0]:
-            nxt = self._sample(logits[slot])
-            self.last_token[slot] = nxt
-            self.lengths[slot] += 1
-            self.budget[slot] -= 1
-            uid = int(self.slot_uid[slot])
-            val = (int(np.ravel(nxt)[0]) if not self.cfg.n_codebooks
-                   else list(map(int, nxt)))
-            self.results[uid].tokens.append(val)
-            eos = (self.sc.eos_id is not None
-                   and not self.cfg.n_codebooks and val == self.sc.eos_id)
-            if eos or self.budget[slot] <= 0 \
-                    or self.lengths[slot] >= self.sc.s_max - 1:
-                self.active[slot] = False
-                self.slot_uid[slot] = -1
+        with TraceAnnotation("engine.decode"):
+            t = jnp.asarray(self.lengths.astype(np.int32))
+            logits, self.cache = self._decode(
+                self.params, self.cache, t, jnp.asarray(self.last_token))
+            logits = np.asarray(logits)
+        with TraceAnnotation("engine.sample"):
+            for slot in np.nonzero(self.active)[0]:
+                nxt = self._sample(logits[slot])
+                self.last_token[slot] = nxt
+                self.lengths[slot] += 1
+                self.budget[slot] -= 1
+                uid = int(self.slot_uid[slot])
+                val = (int(np.ravel(nxt)[0]) if not self.cfg.n_codebooks
+                       else list(map(int, nxt)))
+                self.results[uid].tokens.append(val)
+                eos = (self.sc.eos_id is not None
+                       and not self.cfg.n_codebooks and val == self.sc.eos_id)
+                if eos or self.budget[slot] <= 0 \
+                        or self.lengths[slot] >= self.sc.s_max - 1:
+                    self.active[slot] = False
+                    self.slot_uid[slot] = -1
         return int(self.active.sum())
 
     def run(self) -> Dict[int, Result]:

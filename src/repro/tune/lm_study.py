@@ -28,6 +28,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config
 from repro.configs.base import ArchConfig
@@ -234,13 +235,14 @@ class LMStudy:
         """``WallClockBackend`` provider: resolve a ``ConfigPoint`` (or a
         bare ``StepKnobs``) to the step's bound kernel occurrence list
         ``[(Signature, thunk, freq)]``; compilation happens here, outside
-        any timed region."""
-        knobs = getattr(point, "payload", point) or point
-        out = []
-        for sig, build, freq in self.kernel_sequence(knobs):
-            fn, args = self._kernel(sig, build)
-            out.append((sig,
-                        (lambda fn=fn, args=args: fn(*args)), freq))
+        any timed region (profiler span ``tuner.kernels_of``)."""
+        with TraceAnnotation("tuner.kernels_of"):
+            knobs = getattr(point, "payload", point) or point
+            out = []
+            for sig, build, freq in self.kernel_sequence(knobs):
+                fn, args = self._kernel(sig, build)
+                out.append((sig,
+                            (lambda fn=fn, args=args: fn(*args)), freq))
         return out
 
     @staticmethod

@@ -25,6 +25,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.policies import Policy
 from repro.core.signatures import Signature
 from repro.core.stats import KernelStats
@@ -107,20 +109,26 @@ class SelectiveTimer:
         ``force=True`` executes and measures even a confident (or globally
         switched-off) kernel — shadow mode: the serving daemon's drift
         detector periodically forces a real sample so live evidence keeps
-        flowing after the skip regime is reached."""
-        st = self._stats(sig)
-        if force or self._should_execute(sig, freq):
+        flowing after the skip regime is reached.
+
+        Profiler spans: ``tuner.decide`` before the thunk and
+        ``tuner.update`` after it; neither covers the thunk."""
+        with TraceAnnotation("tuner.decide"):
+            st = self._stats(sig)
+            execute = force or self._should_execute(sig, freq)
+        if execute:
             t0 = self.clock()
             thunk()
             t = self.clock() - t0
-            st.update(t)
-            self._iter_executed.add(sig)
-            self._nexec += 1
-            self._meas += t
-            charged = t
-            if self.policy.persistent_models and st.is_predictable(
-                    self.policy.tolerance, 1, self.policy.min_samples):
-                self.global_off.add(sig)
+            with TraceAnnotation("tuner.update"):
+                st.update(t)
+                self._iter_executed.add(sig)
+                self._nexec += 1
+                self._meas += t
+                charged = t
+                if self.policy.persistent_models and st.is_predictable(
+                        self.policy.tolerance, 1, self.policy.min_samples):
+                    self.global_off.add(sig)
         else:
             charged = st.mean
             self._nskip += 1
