@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.parallel.sharding import annotate
+from repro.parallel.sharding import annotate, current_rules
 
 _NEG_INF = -1e30
 
@@ -134,14 +134,18 @@ def _t_col(t):
     return t[None, None] if t.ndim == 0 else t[:, None]
 
 
-def decode_attention(q, k, v, *, t, kv_positions, softmax_scale=None):
-    """Single-step attention against a (possibly seq-sharded) KV cache.
+def decode_attention(q, k, v, k_new, v_new, *, t, kv_positions,
+                     softmax_scale=None):
+    """Single-step attention against a (possibly seq-sharded) KV cache and
+    the step's own key and value.
 
-    q: (B, 1, H, dk); k: (B, S, KVH, dk); v: (B, S, KVH, dv); positions
-    beyond ``t`` (exclusive; scalar or per-row (B,)) are masked.  Written
-    globally — when the cache's S dim is sharded over 'model', the SPMD
-    partitioner emits exactly the flash-decode partial-softmax + combine
-    pattern (max/sum all-reduces).
+    q: (B, 1, H, dk); k: (B, S, KVH, dk); v: (B, S, KVH, dv); cache
+    positions from ``t`` on (scalar or per-row (B,)) are masked, and the
+    new token k_new (B, 1, KVH, dk), v_new (B, 1, KVH, dv) takes position
+    ``t``, so the cache is only read here.  Written globally — when the
+    cache's S dim is sharded over 'model', the SPMD partitioner emits
+    exactly the flash-decode partial-softmax + combine pattern (max/sum
+    all-reduces).
     """
     B, _, H, dk = q.shape
     KVH = k.shape[2]
@@ -150,13 +154,17 @@ def decode_attention(q, k, v, *, t, kv_positions, softmax_scale=None):
     qg = q.reshape(B, KVH, G, dk)
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k,
                    preferred_element_type=jnp.float32) * scale
-    valid = (kv_positions[None, :] <= _t_col(t))[:, None, None, :]
+    s1 = jnp.einsum("bhgd,bhd->bhg", qg, k_new[:, 0],
+                    preferred_element_type=jnp.float32) * scale
+    valid = (kv_positions[None, :] < _t_col(t))[:, None, None, :]
     s = jnp.where(valid, s, _NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhgs,bshd->bhgd", (p / l).astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
+    m = jnp.maximum(jnp.max(s, axis=-1), s1)
+    p = jnp.exp(s - m[..., None])
+    p1 = jnp.exp(s1 - m)
+    l = jnp.sum(p, axis=-1) + p1
+    out = jnp.einsum("bhgs,bshd->bhgd", (p / l[..., None]).astype(v.dtype),
+                     v, preferred_element_type=jnp.float32)
+    out = out + (p1 / l)[..., None] * v_new[:, 0, :, None, :]
     return out.reshape(B, 1, H, v.shape[-1]).astype(q.dtype)
 
 
@@ -190,9 +198,11 @@ def attn_block(p, x, cfg, *, positions, kv_chunk=1024):
     return annotate(out, "batch", "seq", "embed"), (k, v)
 
 
-def attn_decode(p, x, cache_kv, cfg, *, t, kv_positions):
+def attn_decode(p, x, cache_kv, cfg, *, t, layer, kv_positions):
     """One-token GQA attention against the cache.  x: (B,1,D).
-    cache_kv: (k, v) with shape (B, S, KV, dh); returns out, (k, v) updated.
+    cache_kv: (k, v), the stacks (P_, B, S, KV, dh) of every layer; attends
+    over this ``layer``'s cache before ``t`` and the new token, writes the
+    new rows at ``t`` and returns out, (k, v).
     """
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     pos = _t_col(t)                     # (1,1) or (B,1)
@@ -202,23 +212,58 @@ def attn_decode(p, x, cache_kv, cfg, *, t, kv_positions):
     cos, sin = rope_tables(pos, cfg.head_dim, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k1 = apply_rope(k1, cos, sin)
+    # read, then write: with the write first, XLA lays the carried cache
+    # out unlike the argument and copies it whole at both ends of the scan
     k, v = cache_kv
-    k = cache_update(k, k1, t)
-    v = cache_update(v, v1, t)
-    o = decode_attention(q, k, v, t=t, kv_positions=kv_positions)
+    o = decode_attention(q, _layer(k, layer), _layer(v, layer), k1, v1, t=t,
+                         kv_positions=kv_positions)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    return annotate(out, "batch", None, "embed"), (k, v)
+    return annotate(out, "batch", None, "embed"), (
+        cache_update(k, k1, t, layer), cache_update(v, v1, t, layer))
 
 
-def cache_update(cache, new, t):
-    """Write ``new`` (B, 1, ...) at sequence position ``t`` (scalar or (B,))
-    of ``cache`` (B, S, ...) via one-hot blend — fully shardable on the S
-    dim (a dynamic-update-slice at a traced index into a sharded dim
-    degrades to gather/scatter under SPMD; the blend stays elementwise)."""
-    S = cache.shape[1]
-    oh = (jnp.arange(S)[None, :] == _t_col(t)).astype(cache.dtype)
-    oh = oh.reshape(oh.shape[:2] + (1,) * (cache.ndim - 2))
-    return cache * (1 - oh) + new.astype(cache.dtype) * oh
+def _layer(stack, layer):
+    return lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def kv_seq_sharded(shape) -> bool:
+    """Whether the active rules shard the sequence dim of a stacked cache
+    leaf of ``shape`` (P_, B, S, ...), divisibility fallback included."""
+    rules = current_rules()
+    if rules is None or rules.mesh is None:
+        return False
+    spec = rules.spec("layers", "batch", "kv_seq", dims=shape[:3])
+    return len(spec) > 2 and spec[2] is not None
+
+
+def cache_update(stack, new, t, layer):
+    """Write ``new`` (B, 1, ...) at sequence position ``t`` (scalar or (B,),
+    below S) of ``layer`` in the stacked cache ``stack`` (P_, B, S, ...).
+
+    Where the sequence dim is unsharded (no rules, or rules that leave
+    ``kv_seq`` whole) each slot's row is one dynamic-update-slice: with the
+    cache donated and carried through the decode's layer scan, a step
+    writes B rows a layer in place.  (One scatter would write them all,
+    but on the TPU it wants the cache row-major, while XLA keeps it with
+    the sequence dim minor for attention: the scatter then costs a copy of
+    the whole cache.)  Where the active rules shard ``kv_seq`` the layer's
+    cache is rewritten by a one-hot blend instead: a write at a traced
+    index into a sharded dim degrades to gather/scatter under SPMD, while
+    the blend stays elementwise."""
+    new = new.astype(stack.dtype)
+    B, S = stack.shape[1], stack.shape[2]
+    if not kv_seq_sharded(stack.shape):
+        rows = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
+        rest = (jnp.int32(0),) * (stack.ndim - 3)
+        for b in range(B):
+            stack = lax.dynamic_update_slice(
+                stack, new[None, b:b + 1],
+                (layer, jnp.int32(b), rows[b]) + rest)
+        return stack
+    oh = (jnp.arange(S)[None, :] == _t_col(t)).astype(stack.dtype)
+    oh = oh.reshape(oh.shape + (1,) * (stack.ndim - 3))
+    blended = _layer(stack, layer) * (1 - oh) + new * oh
+    return lax.dynamic_update_index_in_dim(stack, blended, layer, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -287,9 +332,12 @@ def mla_block(p, x, cfg, *, positions, kv_chunk=1024):
     return annotate(out, "batch", "seq", "embed"), (ckv, k_rope)
 
 
-def mla_decode(p, x, cache, cfg, *, t, kv_positions):
+def mla_decode(p, x, cache, cfg, *, t, layer, kv_positions):
     """Absorbed-matmul MLA decode: attention runs in the latent space; the
-    per-head K/V are never expanded.  cache = (c_kv (B,S,r), k_rope (B,S,dr)).
+    per-head K/V are never expanded.  cache = (c_kv (P_,B,S,r), k_rope
+    (P_,B,S,dr)), the stacks of every layer; attends over this ``layer``'s
+    cache before ``t`` and the new token, writes the new rows at ``t`` and
+    returns out, cache.
     """
     m = cfg.mla
     h = rms_norm(x, p["ln"], cfg.norm_eps)
@@ -302,26 +350,34 @@ def mla_decode(p, x, cache, cfg, *, t, kv_positions):
     kv = jnp.einsum("bsd,dr->bsr", h, p["wkv_a"])
     ckv1 = rms_norm(kv[..., :m.kv_lora], p["kv_ln"], cfg.norm_eps)
     kr1 = apply_rope(kv[..., None, m.kv_lora:], cos, sin)[:, :, 0, :]
-    ckv, k_rope = cache
-    ckv = cache_update(ckv, ckv1, t)
-    k_rope = cache_update(k_rope, kr1, t)
+    ckv, k_rope = _layer(cache[0], layer), _layer(cache[1], layer)
 
-    # absorb W_uk into q: q_lat (B,H,r) = q_nope . W_uk
+    # absorb W_uk into q: q_lat (B,H,r) = q_nope . W_uk; the cache holds
+    # positions before t, the step's own row takes t
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"])[:, 0]
+    q_lat = q_lat.astype(jnp.float32)
+    q_rope = q_rope[:, 0].astype(jnp.float32)
     scale = 1.0 / math.sqrt(m.d_nope + m.d_rope)
-    s = (jnp.einsum("bhr,bsr->bhs", q_lat.astype(jnp.float32),
-                    ckv.astype(jnp.float32))
-         + jnp.einsum("bhk,bsk->bhs", q_rope[:, 0].astype(jnp.float32),
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, ckv.astype(jnp.float32))
+         + jnp.einsum("bhk,bsk->bhs", q_rope,
                       k_rope.astype(jnp.float32))) * scale
-    valid = (kv_positions[None, :] <= _t_col(t))[:, None, :]
+    s1 = (jnp.einsum("bhr,br->bh", q_lat, ckv1[:, 0].astype(jnp.float32))
+          + jnp.einsum("bhk,bk->bh", q_rope,
+                       kr1[:, 0].astype(jnp.float32))) * scale
+    valid = (kv_positions[None, :] < _t_col(t))[:, None, :]
     s = jnp.where(valid, s, _NEG_INF)
-    m_ = jnp.max(s, axis=-1, keepdims=True)
-    pr = jnp.exp(s - m_)
-    pr = pr / jnp.sum(pr, axis=-1, keepdims=True)
-    o_lat = jnp.einsum("bhs,bsr->bhr", pr.astype(ckv.dtype), ckv)
+    m_ = jnp.maximum(jnp.max(s, axis=-1), s1)
+    pr = jnp.exp(s - m_[..., None])
+    p1 = jnp.exp(s1 - m_)
+    l = jnp.sum(pr, axis=-1) + p1
+    o_lat = jnp.einsum("bhs,bsr->bhr", (pr / l[..., None]).astype(ckv.dtype),
+                       ckv)
+    o_lat = o_lat + (p1 / l)[..., None].astype(ckv.dtype) * ckv1
     o = jnp.einsum("bhr,rhk->bhk", o_lat, p["wv_b"])   # absorb W_uv
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None]
-    return annotate(out, "batch", None, "embed"), (ckv, k_rope)
+    return annotate(out, "batch", None, "embed"), (
+        cache_update(cache[0], ckv1, t, layer),
+        cache_update(cache[1], kr1, t, layer))
 
 
 # ---------------------------------------------------------------------------

@@ -413,54 +413,76 @@ class Model:
         return tuple(out)
 
     def decode_step(self, params, cache, t, batch):
-        """One new token.  batch['tokens']: (B,1) [or (B,1,ncb)].
-        Returns (logits (B, V[, ncb->(B,ncb,V)]), new cache)."""
+        """One new token.  batch['tokens']: (B,1) [or (B,1,ncb)]; ``t``: the
+        position written, scalar or per slot (B,), below s_max.
+        Returns (logits (B, V[, ncb->(B,ncb,V)]), new cache).
+
+        Attention and MLA caches travel whole in the layer scan's carry:
+        each layer attends over its cache before ``t`` and the new token,
+        then writes the new row per slot (``L.cache_update``), so jitted
+        with the cache donated, a step writes B rows a layer in place.
+        Where the active rules shard ``kv_seq`` the write is a one-hot
+        blend, which rewrites each layer's cache.  SSM states are small and
+        travel as the scan's xs/ys, rewritten whole."""
         cfg, kn = self.cfg, self.knobs
         x = self._embed(params, batch)           # (B,1,D)
         x = annotate(x, "batch", None, "embed")
         s_max = self._cache_smax(cache)
         kv_positions = jnp.arange(s_max)
+        keyed = [kind in ("attn", "mla") for kind in cfg.pattern]
+        kv = tuple(c if k else None for c, k in zip(cache, keyed))
+        states = tuple(None if k else c for c, k in zip(cache, keyed))
 
-        def body(x, per):
-            per_period, cache_in = per
-            new_caches = []
+        def body(carry, per):
+            x, kv = carry
+            per_period, state_in, layer = per
+            kv, new_states = list(kv), []
             for i, (kind, fk) in enumerate(zip(cfg.pattern, cfg.ffn_pattern)):
                 p = {k[len("mix_"):]: v for k, v in per_period[i].items()
                      if k.startswith("mix_")}
                 pf = {k[len("ffn_"):]: v for k, v in per_period[i].items()
                       if k.startswith("ffn_")}
-                c = cache_in[i]
+                c = state_in[i]
                 if kind == "attn":
-                    h, c = L.attn_decode(p, x, c, cfg, t=t,
-                                         kv_positions=kv_positions)
+                    h, kv[i] = L.attn_decode(p, x, kv[i], cfg, t=t,
+                                             layer=layer,
+                                             kv_positions=kv_positions)
                 elif kind == "mla":
-                    h, c = L.mla_decode(p, x, c, cfg, t=t,
-                                        kv_positions=kv_positions)
+                    h, kv[i] = L.mla_decode(p, x, kv[i], cfg, t=t,
+                                            layer=layer,
+                                            kv_positions=kv_positions)
                 elif kind == "mamba":
-                    h, (cs, ss) = S.mamba_block(
+                    h, c = S.mamba_block(
                         p, x, cfg, chunk=1, conv_state=c[0], ssm_state=c[1])
-                    c = (cs, ss)
                 elif kind == "mlstm":
-                    h, (cs, st) = S.mlstm_block(
+                    h, c = S.mlstm_block(
                         p, x, cfg, chunk=1, conv_state=c[0], state=c[1])
-                    c = (cs, st)
                 else:
-                    h, st = S.slstm_block(p, x, cfg, chunk=1, state=c)
-                    c = st
+                    h, c = S.slstm_block(p, x, cfg, chunk=1, state=c)
                 x = x + h
                 if fk == "dense":
                     x = x + L.ffn_block(pf, x, cfg)
                 elif fk == "moe":
                     x = x + M.moe_ffn(pf, x, cfg, dispatch=kn.moe_dispatch)
-                new_caches.append(c)
-            return x, tuple(new_caches)
+                new_states.append(c)
+            return (x, tuple(kv)), tuple(new_states)
 
         stacked = self._stacked(params)
-        x, new_cache = lax.scan(body, x, (stacked, cache),
-                                unroll=kn.scan_unroll)
+        (x, kv), states = lax.scan(
+            body, (x, kv), (stacked, states, jnp.arange(cfg.n_periods)),
+            unroll=kn.scan_unroll)
         x = L.rms_norm(x, params["final"]["ln"], cfg.norm_eps)
         logits = self._head(params, x)
-        return logits[:, 0], new_cache
+        return logits[:, 0], tuple(c if k else st
+                                   for c, st, k in zip(kv, states, keyed))
+
+    def kv_write_in_place(self, batch_size: int, s_max: int) -> bool:
+        """Whether ``decode_step`` under the active rules writes the
+        attention caches a row per slot (True) or blends them (False)."""
+        shapes = jax.eval_shape(lambda: self.init_cache(batch_size, s_max))
+        return not any(L.kv_seq_sharded(c[0].shape)
+                       for kind, c in zip(self.cfg.pattern, shapes)
+                       if kind in ("attn", "mla"))
 
     def _cache_smax(self, cache):
         for kind, c in zip(self.cfg.pattern, cache):

@@ -4,12 +4,20 @@ Slot-based continuous batching over a fixed-capacity decode batch:
 
 - requests enter a queue; free slots are filled by running ``prefill`` for
   the incoming prompt (right-padded to the slot's capacity) and splicing its
-  cache into the batch cache at the slot index;
-- one ``decode_step`` advances every active slot by a token;
+  cache into the batch cache at the slot index: one jitted function, with
+  the batch cache donated and the slot a traced argument, writes the slot
+  in place and compiles once for every slot;
+- one ``decode_step`` advances every active slot by a token; it is jitted
+  with the cache donated, so each layer writes only its new row per slot
+  in place, except where the rules shard the cache's ``kv_seq`` axis and
+  the step blends the row into the whole cache (``layers.cache_update``);
 - finished slots (eos or max tokens) are retired and refilled.
 
 The decode step is jitted once per (batch capacity, s_max); prefill is
 jitted per prompt-length bucket.  Sampling: greedy or temperature.
+``Engine.counters`` counts the decode steps by the path they took
+(``decode_in_place``, ``decode_blend``) and the admissions
+(``splice_in_place``).
 
 Profiler spans (``jax.profiler.TraceAnnotation``, recorded only while a
 trace is active): once per admitted request ``engine.prefill`` (the
@@ -74,6 +82,13 @@ class Result:
     tokens: List[int] = field(default_factory=list)
 
 
+def _splice(cache, one, slot):
+    """Write a batch-1 cache ``one`` into slot ``slot`` of ``cache``."""
+    return jax.tree.map(
+        lambda big, o: jax.lax.dynamic_update_slice_in_dim(
+            big, o.astype(big.dtype), slot, axis=1), cache, one)
+
+
 class Engine:
     """Single-host engine; rules=None runs unsharded (CPU smoke scale)."""
 
@@ -95,8 +110,14 @@ class Engine:
         self.queue: List[Request] = []
         self.last_token = np.zeros((B,) + self._tok_trailing(), np.int32)
         self._rng = np.random.default_rng(sc.seed)
-        self._decode = jax.jit(self._decode_fn)
+        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
+        self._splice = jax.jit(_splice, donate_argnums=(0,))
         self._prefill_cache: Dict[int, Any] = {}
+        with axis_rules(rules):
+            in_place = model.kv_write_in_place(B, S)
+        self._decode_path = "decode_in_place" if in_place else "decode_blend"
+        self.counters = {"decode_in_place": 0, "decode_blend": 0,
+                         "splice_in_place": 0}
 
     def _tok_trailing(self):
         return (self.cfg.n_codebooks,) if self.cfg.n_codebooks else ()
@@ -106,8 +127,6 @@ class Engine:
     def _decode_fn(self, params, cache, t_per_slot, tokens):
         """t_per_slot: (B,) int32 current positions (ragged batch)."""
         with axis_rules(self.rules):
-            # ragged positions: mask via per-slot t in attention
-            # (decode_step takes scalar t; we pass max and mask by position)
             logits, cache = self.model.decode_step(
                 params, cache, t_per_slot, {"tokens": tokens[:, None]})
         return logits, cache
@@ -145,12 +164,10 @@ class Engine:
                 logits, cache1, _ = fn(self.params,
                                        {"tokens": jnp.asarray(toks)}, at)
             with TraceAnnotation("engine.splice"):
-                # splice the single-request cache into slot `slot`; its
-                # dispatch overlaps the prefill on the device
-                self.cache = jax.tree.map(
-                    lambda big, one: jax.lax.dynamic_update_slice_in_dim(
-                        big, one.astype(big.dtype), int(slot), axis=1),
-                    self.cache, cache1)
+                # its dispatch overlaps the prefill on the device
+                self.cache = self._splice(self.cache, cache1,
+                                          jnp.int32(slot))
+                self.counters["splice_in_place"] += 1
             with TraceAnnotation("engine.first_token"):
                 tok0 = self._sample(np.asarray(logits)[0])
             self.last_token[slot] = tok0
@@ -185,6 +202,7 @@ class Engine:
             t = jnp.asarray(self.lengths.astype(np.int32))
             logits, self.cache = self._decode(
                 self.params, self.cache, t, jnp.asarray(self.last_token))
+            self.counters[self._decode_path] += 1
             logits = np.asarray(logits)
         with TraceAnnotation("engine.sample"):
             for slot in np.nonzero(self.active)[0]:
