@@ -17,8 +17,9 @@ widths (30 layers, d_model 576, vocab 49152) with random weights from
 - tune     an ``AutotuneSession`` over ``LMStudy(...).search_space(4)``
            with ``WallClockBackend``: eager, tolerance 0.3, 3 trials,
            batch 4 x seq 1024;
-- kernels  the Pallas matmul, rmsnorm and flash attention compiled for the
-           chip at smollm widths, each allclose to ``kernels/ref.py``.
+- kernels  the Pallas matmul, rmsnorm and causal flash attention (a full
+           and a ragged length) compiled for the chip at smollm widths,
+           each allclose to ``kernels/ref.py``.
 
 ``--four-chips`` runs only the paper's own distributed workload:
 ``jaxdist`` matmul_3d, tsqr and cholesky_3d on a (2, 1, 2) mesh, so the
@@ -160,8 +161,9 @@ def kernel_cases(*, d_model: int, d_ff: int, n_heads: int, n_kv: int,
         ("rmsnorm ragged rows", "rmsnorm", ((3, 100, d_model),)),
         ("flash prefill", "flash_attention",
          ((1, seq, n_heads, head_dim), (1, seq, n_kv, head_dim))),
-        ("flash decode", "flash_attention",
-         ((1, 1, n_heads, head_dim), (1, seq, n_kv, head_dim))),
+        ("flash ragged", "flash_attention",
+         ((1, seq // 2 + 1, n_heads, head_dim),
+          (1, seq // 2 + 1, n_kv, head_dim))),
     ]
 
 
@@ -202,7 +204,7 @@ def phase_kernels(cases=None, *, interpret: bool = False,
                     jax.random.normal(k2, kvs, jnp.bfloat16),
                     jax.random.normal(k3, kvs, jnp.bfloat16))
             fn = lambda q, k, v: kernels.flash_attention(
-                q, k, v, causal=True, interpret=interpret)
+                q, k, v, interpret=interpret)
             want, rtol, atol = ref.flash_attention_ref(*args), 4e-2, 4e-2
         t0 = time.perf_counter()
         got = jax.block_until_ready(fn(*args))

@@ -7,7 +7,9 @@ Each kernel ships three artifacts:
 
 Kernels present:
   matmul          blocked MXU matmul (128-aligned tiles, f32 accumulator)
-  flash_attention causal GQA flash attention (online softmax over KV tiles)
+  flash_attention causal GQA flash attention with its own backward; the
+                  model's full-sequence self-attention on the TPU
+                  (``models.layers.attn_block``)
   rmsnorm         fused RMS-norm
 
 These correspond to the recurring kernel signatures the paper's technique

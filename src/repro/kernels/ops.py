@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 
-from .flash_attention import flash_attention_pallas
+from . import flash_attention as _flash
 from .matmul import matmul_pallas
 from .rmsnorm import rmsnorm_pallas
 
@@ -35,12 +35,8 @@ def rmsnorm(x, w, *, eps: float = 1e-5, interpret: bool = False, **kw):
                           **kw)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = False,
-                    **kw):
-    """(B, Sq, H, d) layout (model-native); transposes into kernel layout."""
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    ot = flash_attention_pallas(qt, kt, vt, causal=causal,
-                                interpret=_check_backend(interpret), **kw)
-    return ot.transpose(0, 2, 1, 3)
+def flash_attention(q, k, v, *, interpret: bool = False, **kw):
+    """Causal self-attention with its own backward, in the model's layout:
+    q (B, S, H, d), k/v (B, S, KVH, d) -> (B, S, H, d)."""
+    return _flash.flash_attention(q, k, v,
+                                  interpret=_check_backend(interpret), **kw)

@@ -4,10 +4,13 @@ All functions are *global-program* JAX: they never mention mesh axes.
 Sharding is injected via ``annotate(x, 'batch', 'seq', ...)`` logical
 constraints; on a bare CPU (no active rules) those are no-ops.
 
-Attention uses an online-softmax formulation chunked over the KV length
-(``lax.scan``) so the score matrix never materializes at (Sq x Skv) — the
-pure-jnp oracle for the Pallas flash kernel, and the memory-feasible path
-for the 32k prefill cells.
+Full-sequence causal self-attention (``attn_block``) runs on the TPU as
+the Pallas flash kernel with its own backward (``kernels.flash_attention``)
+where no sharding rules are active.  Everywhere else, and for latent
+attention's lazily expanded keys, ``chunked_attention`` runs: an online
+softmax chunked over the KV length (``lax.scan``), so the score matrix
+never materializes at (Sq x Skv); it is also the kernel's jnp reference.
+``ATTENTION_CALLS`` counts the calls of each path as they are traced.
 """
 
 from __future__ import annotations
@@ -20,9 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.flash_attention import flash_attention
 from repro.parallel.sharding import annotate, current_rules
 
 _NEG_INF = -1e30
+
+#: attention calls by path, counted at trace time
+ATTENTION_CALLS = {"flash": 0, "chunked": 0}
 
 
 def rms_norm(x, w, eps: float = 1e-5):
@@ -74,6 +81,7 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal=True,
     the full per-head K/V never materialize).
     Returns (B, Sq, H, dv).
     """
+    ATTENTION_CALLS["chunked"] += 1
     B, Sq, H, dk = q.shape
     if kv_expand is None:
         Skv, KVH = k.shape[1], k.shape[2]
@@ -186,13 +194,29 @@ def gqa_project_qkv(p, x, cfg, positions):
     return q, k, v
 
 
+def flash_applies() -> bool:
+    """Whether causal self-attention takes the Pallas flash kernel: on the
+    TPU, with no sharding rules active (the kernel is one device's)."""
+    rules = current_rules()
+    return (jax.default_backend() == "tpu"
+            and (rules is None or rules.mesh is None))
+
+
 def attn_block(p, x, cfg, *, positions, kv_chunk=1024):
-    """Full-sequence (train/prefill) GQA attention; returns (out, (k, v))."""
+    """Full-sequence (train/prefill) GQA attention; returns (out, (k, v)).
+
+    ``positions`` (S,) are strictly increasing, so index order is causal
+    order.  ``kv_chunk`` is ``chunked_attention``'s chunk and sets nothing
+    on the flash kernel's path."""
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     q, k, v = gqa_project_qkv(p, h, cfg, positions)
-    o = chunked_attention(q, k, v, q_positions=positions,
-                          kv_positions=positions, causal=True,
-                          kv_chunk=kv_chunk)
+    if flash_applies():
+        ATTENTION_CALLS["flash"] += 1
+        o = flash_attention(q, k, v)
+    else:
+        o = chunked_attention(q, k, v, q_positions=positions,
+                              kv_positions=positions, causal=True,
+                              kv_chunk=kv_chunk)
     o = annotate(o, "batch", "seq", "heads", None)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return annotate(out, "batch", "seq", "embed"), (k, v)

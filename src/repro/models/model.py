@@ -41,7 +41,7 @@ class ModelKnobs:
     """Step-function tuning parameters — the configuration space the
     paper's technique searches over for the LM framework (tune/)."""
 
-    kv_chunk: int = 1024          # flash-attention KV chunk
+    kv_chunk: int = 1024          # chunked_attention KV chunk (jnp path)
     moe_dispatch: str = "a2a"     # 'a2a' | 'sort' | 'dense'
     ssm_chunk: int = 256          # mamba/xlstm chunk length
     remat: str = "full"           # 'none' | 'full' | 'dots'

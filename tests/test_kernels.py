@@ -9,6 +9,7 @@ import pytest
 
 from repro.kernels import flash_attention, matmul, rmsnorm
 from repro.kernels import ref
+from repro.kernels.tiles import FlashTiles
 
 KEY = jax.random.PRNGKey(7)
 
@@ -46,24 +47,25 @@ def test_rmsnorm_allclose(shape, dtype):
 
 
 @pytest.mark.parametrize(
-    "dims", [(2, 128, 128, 4, 2, 64),     # square causal GQA
-             (1, 64, 256, 8, 8, 32),      # suffix queries (Sq < Skv)
-             (2, 256, 256, 6, 2, 64),     # multi-tile both ways
-             (1, 96, 96, 3, 1, 16),       # MQA, non-128 sizes
-             (1, 300, 300, 2, 1, 32)])    # partial q block, whole KV
+    "dims", [(2, 128, 4, 2, 64),      # square causal GQA
+             (1, 64, 8, 8, 32),       # MHA, one partial tile
+             (2, 256, 6, 2, 64),      # two tiles
+             (1, 96, 3, 1, 16),       # MQA, non-128 sizes
+             (1, 300, 2, 1, 32)])     # ragged length
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_allclose(dims, dtype):
-    B, Sq, Skv, H, KVH, d = dims
+    B, S, H, KVH, d = dims
     k1, k2, k3 = jax.random.split(KEY, 3)
-    q = jax.random.normal(k1, (B, Sq, H, d), dtype)
-    k = jax.random.normal(k2, (B, Skv, KVH, d), dtype)
-    v = jax.random.normal(k3, (B, Skv, KVH, d), dtype)
-    got = np.asarray(flash_attention(q, k, v, causal=True, interpret=True),
-                     np.float32)
+    q = jax.random.normal(k1, (B, S, H, d), dtype)
+    k = jax.random.normal(k2, (B, S, KVH, d), dtype)
+    v = jax.random.normal(k3, (B, S, KVH, d), dtype)
+    got = flash_attention(q, k, v, interpret=True)
+    assert got.dtype == dtype
     want = np.asarray(ref.flash_attention_ref(q, k, v, causal=True),
                       np.float32)
     tol = 4e-2 if dtype == jnp.bfloat16 else 3e-4
-    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol,
+                               atol=tol)
 
 
 def test_flash_attention_matches_model_reference_path():
@@ -77,9 +79,61 @@ def test_flash_attention_matches_model_reference_path():
     pos = jnp.arange(S)
     a = chunked_attention(q, k, v, q_positions=pos, kv_positions=pos,
                           causal=True, kv_chunk=16)
-    b = flash_attention(q, k, v, causal=True, interpret=True)
+    b = flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-4,
                                atol=3e-4)
+
+
+def _rel_gap(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+# (B, S, H, KVH, d, tiles): tiles None are the shape's own
+# (tiles.flash_tiles); the others make several tiles of unequal sizes, so
+# the causal skips and clamped index maps of every kernel are crossed.
+GRAD_CASES = {
+    "g3-d64-one-tile": (2, 128, 6, 2, 64, None),
+    "g4-d64-tiles": (1, 512, 4, 1, 64,
+                     FlashTiles(fwd=(128, 256), dkv=(256, 128),
+                                dq=(128, 128))),
+    "g3-d64-ragged": (1, 300, 3, 1, 64, None),
+    "g4-d128-tiles": (1, 384, 8, 2, 128,
+                      FlashTiles(fwd=(128, 128), dkv=(128, 384),
+                                 dq=(384, 128))),
+    "g4-d128-ragged": (1, 200, 4, 1, 128, None),
+}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("case", sorted(GRAD_CASES))
+def test_flash_attention_grads(case, remat):
+    """Output and dq, dk, dv (through ``jax.grad``, under ``jax.checkpoint``
+    as the train step's ``remat full`` runs it) against the f32 oracle and
+    the model's chunked_attention."""
+    from repro.models.layers import chunked_attention
+    B, S, H, KVH, d, tiles = GRAD_CASES[case]
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(S + d), 4)
+    q = jax.random.normal(k1, (B, S, H, d))
+    k = jax.random.normal(k2, (B, S, KVH, d))
+    v = jax.random.normal(k3, (B, S, KVH, d))
+    w = jax.random.normal(k4, (B, S, H, d))
+    pos = jnp.arange(S)
+    paths = {
+        "flash": lambda q, k, v: flash_attention(q, k, v, tiles=tiles,
+                                                 interpret=True),
+        "oracle": lambda q, k, v: ref.flash_attention_ref(q, k, v),
+        "chunked": lambda q, k, v: chunked_attention(
+            q, k, v, q_positions=pos, kv_positions=pos, kv_chunk=64)}
+    out = {}
+    for name, attn in paths.items():
+        loss = lambda q, k, v, attn=attn: jnp.sum(attn(q, k, v) * w)
+        if remat and name == "flash":
+            loss = jax.checkpoint(loss)
+        out[name] = (attn(q, k, v),) + jax.grad(loss, (0, 1, 2))(q, k, v)
+    for want in ("oracle", "chunked"):
+        gaps = [_rel_gap(a, b) for a, b in zip(out["flash"], out[want])]
+        assert max(gaps) < 3e-4, (want, gaps)
 
 
 @pytest.mark.parametrize("op", ["matmul", "rmsnorm", "flash_attention"])

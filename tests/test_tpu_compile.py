@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.models.model import Model, ModelKnobs
@@ -77,12 +77,21 @@ def test_rmsnorm_compiles(one_chip, shape, dtype):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("sq", [2048, 1], ids=["prefill", "decode"])
-def test_flash_attention_compiles(one_chip, sq):
-    q = _sds(one_chip, (1, 9, sq, 64))
-    kv = _sds(one_chip, (1, 3, 2048, 64))
-    c = _compile(flash_attention_pallas, q, kv, kv)
-    assert "tpu_custom_call" in c.as_text()
+# (B, S, H, KVH, d): the train and tune cells' attention at smollm-135m
+# and granite-3-8b widths, and a ragged serving prefill
+@pytest.mark.parametrize("dims", [(8, 2048, 9, 3, 64), (4, 1024, 32, 8, 128),
+                                  (1, 1000, 9, 3, 64)],
+                         ids=["smollm", "granite", "prefill"])
+def test_flash_attention_compiles(one_chip, dims):
+    """Forward and backward, as ``jax.grad`` of an f32 loss reaches them."""
+    B, S, H, KVH, d = dims
+    q = _sds(one_chip, (B, S, H, d), jnp.float32)
+    kv = _sds(one_chip, (B, S, KVH, d), jnp.float32)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v)),
+                    (0, 1, 2))
+    text = _compile(grad, q, kv, kv).as_text()
+    assert all(name in text
+               for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))
 
 
 def test_smollm_decode_step_compiles(one_chip):
