@@ -10,9 +10,9 @@ import argparse
 
 import numpy as np
 
+from repro.api import AutotuneSession, SimBackend
 from repro.core.models import Extrapolator
 from repro.core.policies import policy
-from repro.core.tuner import Autotuner
 from repro.linalg.studies import STUDIES, candmc_qr_study
 
 from .common import fmt_table, save_rows
@@ -21,26 +21,27 @@ from .common import fmt_table, save_rows
 def bench_racing(fast=True):
     """Racing vs exhaustive on the Capital study: same winner, less cost."""
     rows = []
+    pol = policy("online", tolerance=0.25)
     for study_name in ("capital-cholesky", "slate-cholesky"):
-        study = STUDIES[study_name]("ci")
-        ex = Autotuner(study, policy("online", tolerance=0.25), trials=3,
-                       seed=0)
-        rep_ex = ex.tune()
-        study2 = STUDIES[study_name]("ci")
-        rc = Autotuner(study2, policy("online", tolerance=0.25), trials=1,
-                       seed=0)
-        rep_rc = rc.tune_racing(max_rounds=4 if fast else 8)
-        exhaustive_iters = 3 * len(study.configs)
+        space = STUDIES[study_name]("ci")
+        rep_ex = AutotuneSession(space, SimBackend(), policy=pol, trials=3,
+                                 seed=0).run()
+        rep_rc = AutotuneSession(
+            space, SimBackend(), policy=pol, trials=1, seed=0,
+            search="racing",
+            search_options={"max_rounds": 4 if fast else 8}).run()
+        best = rep_rc.extra["best"]
+        racing_iters = rep_rc.extra["total_iterations"]
+        exhaustive_iters = 3 * len(space)
         rows.append({
             "study": study_name,
             "exhaustive_best": rep_ex.true_best.name,
-            "racing_best": rep_rc.best,
-            "agree": rep_rc.best == rep_ex.chosen.name
-            or rep_rc.best == rep_ex.true_best.name,
+            "racing_best": best,
+            "agree": best == rep_ex.chosen.name
+            or best == rep_ex.true_best.name,
             "exhaustive_iters": exhaustive_iters,
-            "racing_iters": rep_rc.total_iterations,
-            "iter_reduction": exhaustive_iters / max(
-                rep_rc.total_iterations, 1),
+            "racing_iters": racing_iters,
+            "iter_reduction": exhaustive_iters / max(racing_iters, 1),
         })
     print("\n== racing search (beyond paper) ==")
     print(fmt_table(rows, ("study", "exhaustive_best", "racing_best",
@@ -53,12 +54,12 @@ def bench_racing(fast=True):
 def bench_extrapolation(fast=True):
     """Fit t ~ a*flops + b*bytes + c per op family on CANDMC's kernels;
     validate on held-out (larger) signatures."""
-    study = candmc_qr_study("ci")
-    tuner = Autotuner(study, policy("conditional", tolerance=0.25),
-                      trials=2, seed=0)
-    rt, critter = tuner.runtime, tuner.critter
+    space = candmc_qr_study("ci")
+    run = SimBackend().open(space, policy("conditional", tolerance=0.25),
+                            seed=0)
+    rt, critter = run.runtime, run.critter
     # collect statistics from two full executions of the first config
-    prog = study.configs[0].make_program(tuner.world)
+    prog = space.points[0].payload(run.world)
     for _ in range(2):
         rt.run(prog, force_execute=True, update_stats=True)
     kbar = critter.pooled_kbar()
@@ -107,11 +108,10 @@ def bench_extrapolate_policy(fast=True):
     rows = []
     for tol in ((0.25,) if fast else (0.5, 0.25, 0.125)):
         for extra in (False, True):
-            study = candmc_qr_study("ci")
-            rep = Autotuner(study,
-                            policy("online", tolerance=tol,
-                                   extrapolate=extra),
-                            trials=3, seed=0).tune()
+            rep = AutotuneSession(candmc_qr_study("ci"), SimBackend(),
+                                  policy=policy("online", tolerance=tol,
+                                                extrapolate=extra),
+                                  trials=3, seed=0).run()
             rows.append({"tolerance": tol, "extrapolate": extra,
                          "speedup": rep.speedup,
                          "mean_error": rep.mean_error,

@@ -10,8 +10,11 @@ gates a quick run's warm AND cold throughput against the committed baseline
 swings 2-4x).
 
 Throughput metrics per world size (PR 4 added the cold split; a fifth
-field, ``events_per_sec_cold_scalar``, records the same-session
-``trace_cache=False`` reference the batched-cold ratio is taken against):
+field, ``events_per_sec_cold_scalar``, records the same-session reference
+the batched-cold ratio is taken against: ``Runtime.run_scalar
+(force_execute=True)``, the recorded program, recording pass included,
+through the scalar interceptions with one timer draw per event — the
+seed engine's interleaved pass it used to time is gone):
 
 - ``events_per_sec``              — all iterations;
 - ``events_per_sec_warm``         — selective iterations after warmup: the
@@ -27,9 +30,9 @@ PR 9 adds the compiled-warm split and the counter-RNG cold metric:
 
 - ``events_per_sec_warm`` now measures the compiled warm program
   (segmented, vectorized replay — the default selective path);
-- ``events_per_sec_warm_scalar`` — a same-session ``compiled=False``
-  reference running the scalar event-program interpreter over the same
-  protocol; ``warm_speedup_vs_scalar`` is their ratio (the CI gate's
+- ``events_per_sec_warm_scalar`` — a same-session reference running the
+  same protocol through ``Runtime.run_scalar`` (the scalar event-program
+  interpreter); ``warm_speedup_vs_scalar`` is their ratio (the CI gate's
   compiled-throughput signal);
 - ``events_per_sec_cold_counter`` — the straggler-ON recording run under
   the counter-based (Philox-style) RNG discipline, whose mixed
@@ -84,8 +87,7 @@ GEOMETRIES = {
 
 
 def _setup(world_size: int, *, pol: str, tol: float, seed: int,
-           straggler_p=None, trace_cache: bool = True,
-           compiled: bool = True, counter_rng: bool = False,
+           straggler_p=None, counter_rng: bool = False,
            program_cache=None):
     pr, pc, n, tile = GEOMETRIES[world_size]
     world = World(world_size)
@@ -94,7 +96,6 @@ def _setup(world_size: int, *, pol: str, tol: float, seed: int,
     cm = CostModel(KNL_STAMPEDE2, allocation=0, seed=seed,
                    counter_rng=counter_rng, **kw)
     rt = Runtime(world, critter, cm.sample, seed=seed,
-                 trace_cache=trace_cache, compiled=compiled,
                  program_cache=program_cache)
     prog = slate_cholesky.make_program(world, n=n, tile=tile, lookahead=1,
                                        pr=pr, pc=pc)
@@ -107,41 +108,41 @@ def _setup(world_size: int, *, pol: str, tol: float, seed: int,
     return rt, prog
 
 
+def _timed_cold(run, prog):
+    """One forced iteration through ``run`` (``Runtime.run`` or
+    ``Runtime.run_scalar``): its report and wall seconds."""
+    t0 = time.perf_counter()
+    res = run(prog, force_execute=True)
+    return res, time.perf_counter() - t0
+
+
 def bench_cold(world_size: int, *, pol: str = "online", tol: float = 0.25,
-               seed: int = 0, straggler_p=0.0, trace_cache: bool = True,
+               seed: int = 0, straggler_p=0.0,
                counter_rng: bool = False, program_cache=None) -> dict:
     """One recording (forced) run in isolation — the batched cold path
     when ``straggler_p == 0`` (vectorized pre-draw), the scalar-fallback
     cold path otherwise (unless ``counter_rng=True``, where the
     counter-based draw discipline batches mixed normal/uniform draws even
-    with stragglers on), and with ``trace_cache=False`` the seed-style
-    interleaved scalar pass that serves as the same-session reference the
-    batched speedup is measured against (the shared CI box swings 2-4x
-    between sessions, so only within-session ratios are stable).
+    with stragglers on).
 
     With ``program_cache`` (PR 10) the run consults the cross-task
     program cache keyed by the geometry's structural fingerprint: against
     a warmed cache the recording pass is replaced by artifact replay, so
     the wall measures deserialization + forced execution."""
     rt, prog = _setup(world_size, pol=pol, tol=tol, seed=seed,
-                      straggler_p=straggler_p, trace_cache=trace_cache,
-                      counter_rng=counter_rng, program_cache=program_cache)
-    t0 = time.perf_counter()
-    res = rt.run(prog, force_execute=True)
-    dt = time.perf_counter() - t0
+                      straggler_p=straggler_p, counter_rng=counter_rng,
+                      program_cache=program_cache)
+    res, dt = _timed_cold(rt.run, prog)
     return {"events": res.events, "wall_s": round(dt, 4),
             "events_per_sec": round(res.events / dt, 1),
             "straggler_p": straggler_p, "recordings": rt.recordings,
             "cache_hits": rt.cache_hits}
 
 
-def _study_session(world_size: int, *, pol: str, tol: float, seed: int,
-                   selective_iters: int, warmup: int,
-                   compiled: bool) -> dict:
+def _study_session(run, prog, *, selective_iters: int, warmup: int) -> dict:
     """One tuner-pattern session (1 forced + ``selective_iters`` selective
-    iterations) with per-iteration timings and the warm aggregate."""
-    rt, prog = _setup(world_size, pol=pol, tol=tol, seed=seed,
-                      compiled=compiled)
+    iterations) through ``run`` (``Runtime.run`` or ``Runtime.run_scalar``)
+    with per-iteration timings and the warm aggregate."""
     runs = []
     total_events = 0
     total_wall = 0.0
@@ -150,7 +151,7 @@ def _study_session(world_size: int, *, pol: str, tol: float, seed: int,
     for i in range(1 + selective_iters):
         force = i == 0
         t0 = time.perf_counter()
-        res = rt.run(prog, force_execute=force)
+        res = run(prog, force_execute=force)
         dt = time.perf_counter() - t0
         runs.append({"force_execute": force, "events": res.events,
                      "executed": res.executed, "skipped": res.skipped,
@@ -162,7 +163,7 @@ def _study_session(world_size: int, *, pol: str, tol: float, seed: int,
             warm_events += res.events
             warm_wall += dt
     return {
-        "rt": rt, "prog": prog, "runs": runs,
+        "runs": runs,
         "total_events": total_events, "total_wall": total_wall,
         "warm_rate": round(warm_events / warm_wall, 1)
         if warm_wall > 0 else 0.0,
@@ -179,8 +180,8 @@ def bench_study(world_size: int, *, pol: str = "online", tol: float = 0.25,
     (straggler branch off, vectorized pre-draw).
 
     PR 9: the selective iterations run through the compiled warm program
-    (segmented vectorized replay) by default; a second, ``compiled=False``
-    session over the same protocol provides the same-session scalar-warm
+    (segmented vectorized replay); a second session over the same protocol
+    through ``Runtime.run_scalar`` provides the same-session scalar-warm
     reference the compiled speedup is taken against, and the straggler
     cold pair (counter-RNG batched vs legacy scalar-fallback) measures the
     PR-5 residual fix.
@@ -196,21 +197,21 @@ def bench_study(world_size: int, *, pol: str = "online", tol: float = 0.25,
     from repro.simmpi.program import ProgramCache
 
     pr, pc, n, tile = GEOMETRIES[world_size]
-    comp = _study_session(world_size, pol=pol, tol=tol, seed=seed,
-                          selective_iters=selective_iters, warmup=warmup,
-                          compiled=True)
-    scal = _study_session(world_size, pol=pol, tol=tol, seed=seed,
-                          selective_iters=selective_iters, warmup=warmup,
-                          compiled=False)
+    rt, prog = _setup(world_size, pol=pol, tol=tol, seed=seed)
+    comp = _study_session(rt.run, prog, selective_iters=selective_iters,
+                          warmup=warmup)
+    segmeta = rt.warm_meta(prog)
+    rt, prog = _setup(world_size, pol=pol, tol=tol, seed=seed)
+    scal = _study_session(rt.run_scalar, prog,
+                          selective_iters=selective_iters, warmup=warmup)
     runs = comp["runs"]
     total_events = comp["total_events"]
     total_wall = comp["total_wall"]
-    segmeta = comp["rt"].warm_meta(comp["prog"])
     # batched-vs-scalar cold pairs: alternate the variants and keep
     # min-wall of each so the pairing survives the box's second-scale
     # throughput swings (a single A-then-B measurement can land A in a
     # slow patch and B in a fast one, inverting the ratio).  Two pairs:
-    # straggler-off batched pre-draw vs interleaved scalar (PR 4), and
+    # straggler-off batched pre-draw vs the scalar reference, and
     # straggler-ON counter-RNG batched vs legacy scalar fallback (PR 9 —
     # the PR-5 residual: mixed normal/uniform draws batched per segment).
     b_walls, s_walls, cb_walls, cs_walls, cc_walls = [], [], [], [], []
@@ -224,8 +225,9 @@ def bench_study(world_size: int, *, pol: str = "online", tol: float = 0.25,
         for _ in range(cold_repeats):
             b = bench_cold(world_size, pol=pol, tol=tol, seed=seed,
                            straggler_p=0.0)
-            s = bench_cold(world_size, pol=pol, tol=tol, seed=seed,
-                           straggler_p=0.0, trace_cache=False)
+            rt, prog = _setup(world_size, pol=pol, tol=tol, seed=seed,
+                              straggler_p=0.0)
+            _, s_wall = _timed_cold(rt.run_scalar, prog)
             cb = bench_cold(world_size, pol=pol, tol=tol, seed=seed,
                             straggler_p=0.002, counter_rng=True)
             cs = bench_cold(world_size, pol=pol, tol=tol, seed=seed,
@@ -238,7 +240,7 @@ def bench_study(world_size: int, *, pol: str = "online", tol: float = 0.25,
             assert cc["recordings"] == 0 and cc["cache_hits"] == 1, (
                 f"cached cold run did not replay from the cache: {cc}")
             b_walls.append(b["wall_s"])
-            s_walls.append(s["wall_s"])
+            s_walls.append(round(s_wall, 4))
             cb_walls.append(cb["wall_s"])
             cs_walls.append(cs["wall_s"])
             cc_walls.append(cc["wall_s"])
@@ -303,9 +305,9 @@ def verify_cold_path(world_size: int = 16) -> dict:
     1. The recorded event program is identical whether the cold run drew
        its samples batched (straggler off) or through the scalar fallback
        (straggler on): recording is structural, timing-independent.
-    2. A batched cold run and an unbatched (``trace_cache=False``,
-       interleaved scalar) cold run over the same cost model produce
-       bit-identical reports and leave the sampler RNG in the same state.
+    2. A batched cold run and ``run_scalar``'s forced run (one scalar
+       draw per event) over the same cost model produce bit-identical
+       reports and leave the sampler RNG in the same state.
 
     Returns a small summary dict; raises AssertionError on any mismatch.
     Wired into ``--verify``, ``scripts/check.sh --stage engine`` and
@@ -318,10 +320,11 @@ def verify_cold_path(world_size: int = 16) -> dict:
 
     reports = []
     states = []
-    for trace_cache in (True, False):
+    for scalar in (False, True):
         rt, prog = _setup(world_size, pol="online", tol=0.25, seed=0,
-                          straggler_p=0.0, trace_cache=trace_cache)
-        res = rt.run(prog, force_execute=True)
+                          straggler_p=0.0)
+        res = (rt.run_scalar if scalar else rt.run)(prog,
+                                                    force_execute=True)
         reports.append({f: getattr(res, f) for f in _REPORT_FIELDS})
         states.append(rt._rng.bit_generator.state)
     assert reports[0] == reports[1], (
@@ -356,17 +359,17 @@ def _engine_snapshot(critter) -> tuple:
 
 
 def _selective_trace(world_size: int, *, pol: str, straggler_p: float,
-                     trace_cache: bool, compiled: bool,
-                     iters: int = 3) -> list:
-    """Forced run + ``iters`` selective iterations; returns per-iteration
-    reports, per-iteration engine-state fingerprints and the final RNG
+                     scalar: bool, iters: int = 3) -> list:
+    """Forced run + ``iters`` selective iterations, through ``run_scalar``
+    when ``scalar`` else ``run``; returns per-iteration reports,
+    per-iteration engine-state fingerprints and the final RNG
     bit-generator state."""
     rt, prog = _setup(world_size, pol=pol, tol=0.25, seed=0,
-                      straggler_p=straggler_p, trace_cache=trace_cache,
-                      compiled=compiled)
+                      straggler_p=straggler_p)
+    run = rt.run_scalar if scalar else rt.run
     trace = []
     for i in range(1 + iters):
-        res = rt.run(prog, force_execute=(i == 0))
+        res = run(prog, force_execute=(i == 0))
         trace.append(tuple(getattr(res, f) for f in _REPORT_FIELDS))
         trace.append(_engine_snapshot(rt.critter))
     trace.append(rt._rng.bit_generator.state)
@@ -378,10 +381,10 @@ def verify_compiled_path(world_size: int = 16) -> dict:
     bit-identical to the scalar engine.
 
     For each policy x straggler-branch combination the tuner protocol
-    (forced run + 3 selective iterations) is run three ways — compiled
-    warm program, scalar event-program interpreter (``compiled=False``)
-    and the seed-style live engine (``trace_cache=False``) — and all
-    three must agree on every iteration report field, the full engine
+    (forced run + 3 selective iterations) is run two ways — ``run`` (the
+    batched cold run, then the compiled warm program) and the scalar
+    reference ``run_scalar`` — and both must agree on every iteration
+    report field, the full engine
     state after every iteration (statistics, mean mirrors, counts, path
     profiles, clocks, Welford accumulators, global skip state) and the
     sampler RNG stream.  Raises AssertionError on any divergence.
@@ -393,22 +396,14 @@ def verify_compiled_path(world_size: int = 16) -> dict:
     checked = 0
     for pol in ("online", "eager"):
         for straggler_p in (0.0, 0.002):
-            live = _selective_trace(world_size, pol=pol,
-                                    straggler_p=straggler_p,
-                                    trace_cache=False, compiled=True)
             scalar = _selective_trace(world_size, pol=pol,
-                                      straggler_p=straggler_p,
-                                      trace_cache=True, compiled=False)
+                                      straggler_p=straggler_p, scalar=True)
             comp = _selective_trace(world_size, pol=pol,
-                                    straggler_p=straggler_p,
-                                    trace_cache=True, compiled=True)
-            for i, (a, b, c) in enumerate(zip(live, scalar, comp)):
-                assert a == c, (f"compiled path diverged from live engine "
+                                    straggler_p=straggler_p, scalar=False)
+            for i, (a, b) in enumerate(zip(scalar, comp)):
+                assert a == b, (f"compiled path diverged from run_scalar "
                                 f"({pol}, straggler={straggler_p}, "
                                 f"trace step {i})")
-                assert b == c, (f"compiled path diverged from scalar "
-                                f"interpreter ({pol}, "
-                                f"straggler={straggler_p}, trace step {i})")
             checked += 1
     rt, prog = _setup(world_size, pol="online", tol=0.25, seed=0)
     meta = rt.warm_meta(prog)
@@ -423,7 +418,7 @@ def verify_counter_rng(world_size: int = 16) -> dict:
     optimization: (1) per-event scalar draws and per-segment batched
     draws over the same counter range are bit-identical, including the
     straggler branch; (2) with ``counter_rng=True`` the batched cold path
-    and the live engine produce identical reports and leave the draw
+    and ``run_scalar`` produce identical reports and leave the draw
     cursor at the same index (the counter-mode analogue of the
     bit-generator state check); (3) selective iterations agree too."""
     import numpy as np
@@ -449,22 +444,23 @@ def verify_counter_rng(world_size: int = 16) -> dict:
     assert cm_a.draw_index == cm_b.draw_index == 3 * len(sigs), (
         f"draw cursors diverged: {cm_a.draw_index} vs {cm_b.draw_index}")
 
-    # (2)+(3) batched cold + compiled selective vs live, counter mode,
+    # (2)+(3) batched cold + compiled selective vs run_scalar, counter mode,
     # straggler branch ON (the PR-5 residual configuration)
     cursors = []
     traces = []
     pr, pc, n, tile = GEOMETRIES[world_size]
-    for trace_cache in (True, False):
+    for scalar in (False, True):
         w = World(world_size)
         c = Critter(w, policy("online", tolerance=0.25))
         cm = CostModel(KNL_STAMPEDE2, allocation=0, seed=0,
                        straggler_p=0.002, counter_rng=True)
-        rt = Runtime(w, c, cm.sample, seed=0, trace_cache=trace_cache)
+        rt = Runtime(w, c, cm.sample, seed=0)
+        run = rt.run_scalar if scalar else rt.run
         prog = slate_cholesky.make_program(w, n=n, tile=tile, lookahead=1,
                                            pr=pr, pc=pc)
         trace = []
         for i in range(3):
-            res = rt.run(prog, force_execute=(i == 0))
+            res = run(prog, force_execute=(i == 0))
             trace.append(tuple(getattr(res, f) for f in _REPORT_FIELDS))
             trace.append(_engine_snapshot(c))
         traces.append(trace)

@@ -27,7 +27,6 @@ import time
 from typing import List, Optional, Sequence
 
 from repro.api import AutotuneSession, SimBackend, StatisticsBank
-from repro.core.tuner import space_of_study
 from repro.linalg.studies import STUDIES
 
 from .common import ART, fmt_table, save_rows
@@ -39,7 +38,7 @@ COLS = ("study", "run", "top_k", "dispatched", "coverage", "winner",
 def _study_rows(study: str, scale: str, top_ks: Sequence[int],
                 bank: Optional[StatisticsBank], *, policy: str,
                 tolerance: float, trials: int, seed: int) -> List[dict]:
-    space = space_of_study(STUDIES[study](scale))
+    space = STUDIES[study](scale)
 
     def session(**kw):
         return AutotuneSession(space, backend=SimBackend(), policy=policy,

@@ -27,7 +27,6 @@ import time
 from typing import List, Optional
 
 from repro.api import AutotuneSession, SimBackend
-from repro.core.tuner import space_of_study
 from repro.linalg.studies import STUDIES
 
 from .common import ART, fmt_table, save_rows
@@ -53,7 +52,7 @@ def run(study: str = "capital-cholesky", scale: str = "ci",
         tight_tolerance: float = 0.0625, trials: int = 3,
         discount: float = 0.5,
         bank_path: Optional[str] = None) -> List[dict]:
-    space = space_of_study(STUDIES[study](scale))
+    space = STUDIES[study](scale)
 
     def session(**kw):
         return AutotuneSession(space, backend=SimBackend(), policy=policy,

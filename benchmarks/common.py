@@ -13,7 +13,6 @@ from typing import List, Optional, Sequence
 
 from repro.api import AutotuneSession, SimBackend, StudyResult
 from repro.core.policies import POLICIES
-from repro.core.tuner import space_of_study
 
 ART = os.path.join(os.path.dirname(__file__), "results")
 
@@ -29,7 +28,7 @@ def sweep_session(make_study, *, trials: int = 3, scale: str = "ci",
     warm-starting every study of the sweep (repro.api.transfer);
     ``prior_discount=1.0`` keeps its full evidence (same-machine,
     same-cost-model banks need no widening)."""
-    return AutotuneSession(space_of_study(make_study(scale)),
+    return AutotuneSession(make_study(scale),
                            backend=SimBackend(), trials=trials,
                            prior=prior, prior_discount=prior_discount)
 

@@ -393,10 +393,9 @@ import sys
 
 sys.path.insert(0, "tests")
 from repro.api import AutotuneSession, SimBackend, StatisticsBank
-from repro.core.tuner import space_of_study
 from golden_runner import _studies
 
-space = space_of_study(_studies()[1])       # tiny Capital study, world 8
+space = _studies()[1]                       # tiny Capital study, world 8
 
 def session(**kw):
     return AutotuneSession(space, backend=SimBackend(), policy="eager",

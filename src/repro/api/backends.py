@@ -163,8 +163,7 @@ class SimBackend(Backend):
     """Virtual-machine measurement: simmpi ``Runtime`` + ``Critter``.
 
     Point payloads are program factories ``make_program(world) ->
-    program_factory(rank, world)`` (the ``Configuration.make_program``
-    convention of the linalg studies).
+    program_factory(rank, world)``, as the linalg studies build them.
     """
 
     name = "sim"

@@ -8,10 +8,6 @@ optimum selection quality — plus backend/search provenance, and round-trip
 losslessly through JSON (``to_json``/``from_json``), which is what session
 checkpointing, the parallel sweep's result pipes, and the
 ``benchmarks/results/`` writers all rely on.
-
-``repro.core.tuner`` re-exports these under their historical names
-(``ConfigRecord``, ``StudyReport``) for pinned tests; new code should
-import from ``repro.api``.
 """
 
 from __future__ import annotations

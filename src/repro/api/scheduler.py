@@ -13,9 +13,8 @@ benchmark drivers.  This module lifts it into one subsystem:
   * ``InProcessExecutor`` — synchronous, in this process (the serial
     driver; the only executor for backends that are not ``parallel_safe``);
   * ``ForkExecutor``      — ``os.fork`` children, results over pipes
-    (subsumes the old ``repro.api.parallel`` pool: study spaces carry
-    closures that do not pickle, and a forked child inherits them — plus
-    the parent's warm imports — for free);
+    (study spaces carry closures that do not pickle, and a forked child
+    inherits them — plus the parent's warm imports — for free);
   * ``RemoteExecutor``    — socket-connected ``python -m repro.api.worker``
     processes speaking newline-delimited JSON; each worker owns its own
     (space, backend) built from an import spec and executes the same task
@@ -805,10 +804,10 @@ class Scheduler:
 def run_tasks(tasks: Sequence[Any], runner: Callable[[Any], dict], *,
               workers: int = 1,
               on_result: Callable[[int, dict], None] = None) -> List[dict]:
-    """Historical ``repro.api.parallel.run_tasks`` API over the scheduler:
-    run ``runner(task) -> json-able dict`` over every task, ``workers`` at
-    a time, returning results in task order; ``on_result(index, res)``
-    fires as each result lands."""
+    """A plain fork pool over the scheduler: run ``runner(task) ->
+    json-able dict`` over every task, ``workers`` at a time, returning
+    results in task order; ``on_result(index, res)`` fires as each result
+    lands."""
     tasks = list(tasks)
     if workers <= 1 or len(tasks) <= 1 or not fork_available():
         executor: Executor = InProcessExecutor()

@@ -1,4 +1,4 @@
-"""Search drivers over a backend run (lifted out of ``core.tuner``).
+"""Search drivers over a backend run.
 
 ``exhaustive`` is the paper's evaluation protocol (§VI.A): for each
 configuration, one full reference execution, the policy's optional charged
@@ -20,9 +20,8 @@ roofline lower bounds against an optional measured incumbent
 for statistical-confidence arbitration — paper-geometry sweeps touching
 <10% of the grid with the same winners.
 
-All produce the uniform ``ConfigRecord``/``StudyResult`` rows; the
-``Autotuner`` shim in ``core.tuner`` delegates here, so the sim goldens
-pin these drivers bit-for-bit.
+All produce the uniform ``ConfigRecord``/``StudyResult`` rows; the sim
+goldens (``tests/golden_reports.json``) pin ``exhaustive`` bit-for-bit.
 """
 
 from __future__ import annotations
@@ -39,9 +38,7 @@ from repro.core.stats import t_quantile_975
 from .result import ConfigRecord
 from .space import ConfigPoint, SearchSpace
 
-# NOTE: this module deliberately does not import .backends (the run is
-# duck-typed) — core.tuner imports these drivers at module level, and a
-# .backends dependency would close an import cycle through repro.core.
+# the run is duck-typed: this module does not import .backends
 
 SEARCHES = ("exhaustive", "racing", "model_guided")
 

@@ -250,8 +250,8 @@ class StatisticsBank:
         least-squares fit ``log t_target = a * log t_source + b`` over the
         matched pairs' means (a plain median mean-ratio below
         ``min_matches`` pairs; identity with no matches), then
-        evidence-kept via ``KernelStats.scaled``.  Target-only kernels pass
-        through unchanged.
+        evidence-kept via ``KernelStats.scaled_to``.  Target-only kernels
+        pass through unchanged.
         """
         src, tgt = self.entries, target.entries
         matched = [k for k in src if k in tgt
@@ -275,9 +275,8 @@ class StatisticsBank:
         for k, s in src.items():
             if k in out:
                 continue
-            scale = math.exp(a * math.log(s.mean) + b) / s.mean \
-                if s.mean > 0 else 1.0
-            out[k] = s.scaled(scale)
+            out[k] = s.scaled_to(math.exp(a * math.log(s.mean) + b)) \
+                if s.mean > 0 else s.copy()
         for k, t in tgt.items():
             if k not in out:
                 out[k] = t.copy()

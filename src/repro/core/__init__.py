@@ -9,8 +9,6 @@ from .channels import Channel, ChannelRegistry, ranks_to_channel
 from .policies import POLICIES, Policy, policy
 from .critter import Critter, IterationReport
 from .models import Extrapolator, FamilyModel
-from .tuner import (Autotuner, Configuration, ConfigRecord, RacingReport,
-                    Study, StudyReport)
 
 __all__ = [
     "Signature", "SignatureInterner", "comp_sig", "comm_sig", "p2p_sig",
@@ -21,6 +19,4 @@ __all__ = [
     "POLICIES", "Policy", "policy",
     "Critter", "IterationReport",
     "Extrapolator", "FamilyModel",
-    "Autotuner", "Configuration", "ConfigRecord", "RacingReport",
-    "Study", "StudyReport",
 ]

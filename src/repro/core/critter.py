@@ -47,8 +47,8 @@ from .policies import Policy
 from .signatures import Signature
 from .stats import KernelStats
 
-# Compiled warm-program opcodes — produced by the runtime's segment
-# compiler (simmpi.runtime._build_warm), consumed by ``Critter.run_warm``.
+# Compiled warm-program opcodes — produced by the segment compiler
+# (simmpi.program.build_warm), consumed by ``Critter.run_warm``.
 # W_CHEAD / W_BHEAD are comp / comp-block entries that additionally head a
 # fused per-rank segment (a maximal run of that rank's computation events
 # between two of its skip-decision / communication boundaries); their
@@ -125,7 +125,7 @@ class Critter:
         # interner — the runtime interns into the same table)
         self._sigs = world.interner.sigs
         # cross-study transfer: resolver Signature -> KernelStats-or-None
-        # (repro.api.transfer); consumed lazily as signatures are interned
+        # (api.transfer); consumed lazily as signatures are interned
         self._prior_lookup = None
         self._prior_upto = 0
         # list-backed per-rank scalars, live only inside one forced run

@@ -115,7 +115,7 @@ def _bucket(nbytes: int) -> int:
 
 def structural_key(sig: Signature, world_size: int) -> str:
     """World-independent identity of a kernel signature, for cross-study
-    statistics transfer (``repro.api.transfer``).
+    statistics transfer (``api.transfer``).
 
     Two studies only share a ``Signature`` object space per interner, and a
     comm signature's ``(comm_size, comm_stride)`` is meaningful only

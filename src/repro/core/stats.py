@@ -265,7 +265,7 @@ class KernelStats:
     # The sufficient statistics (n, mean, m2) plus the reporting extras
     # (total, min, max) fully determine every derived quantity above, so a
     # bank of exported KernelStats can re-enter a later study as a prior
-    # (repro.api.transfer) with nothing lost.  The memo caches are NOT
+    # (api.transfer) with nothing lost.  The memo caches are NOT
     # exported: they are keyed on n and rebuild on first use.
 
     def to_json(self) -> dict:
@@ -353,12 +353,16 @@ class KernelStats:
         return KernelStats(nd, mean, m2, total, self.min_t, self.max_t,
                            self.last_updated)
 
-    def scaled(self, a: float) -> "KernelStats":
-        """The statistics of ``a * X`` — the affine (through-origin) image
-        used when a fitted source->target time map rescales a transferred
-        kernel distribution."""
+    def scaled_to(self, mean: float) -> "KernelStats":
+        """The statistics of ``a * X`` with ``a = mean / self.mean`` — the
+        affine (through-origin) image used when a fitted source->target
+        time map sends a transferred kernel's mean to ``mean``.  The image
+        carries ``mean`` itself, not ``a * self.mean``: that product can
+        round an ulp off, and two kernels the map sends to one value would
+        then come out inverted.  The extremes are clamped to bracket it."""
         if self.n == 0:
             return KernelStats()
-        return KernelStats(self.n, a * self.mean, a * a * self.m2,
-                           a * self.total, a * self.min_t, a * self.max_t,
-                           self.last_updated)
+        a = mean / self.mean
+        return KernelStats(self.n, mean, a * a * self.m2, a * self.total,
+                           min(a * self.min_t, mean),
+                           max(a * self.max_t, mean), self.last_updated)

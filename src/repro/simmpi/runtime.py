@@ -39,10 +39,16 @@ Hot-path design (see also core.critter):
   subsequent iterations (the common case — the tuner runs trials-many
   iterations per configuration).  Runs of consecutive computation kernels
   of one rank are fused into blocks that the profiler can charge in one
-  vectorized step.  Pass ``trace_cache=False`` for programs whose op
-  stream is nondeterministic or feedback-dependent; that path interleaves
-  recording-free matching with scalar interception exactly like the seed
-  engine;
+  vectorized step;
+- **three execution paths, chosen from what the run observes**: a forced
+  run goes through the batched cold interpreter, a selective run whose
+  policy is warm-eligible through the compiled warm replay
+  (``Critter.run_warm``), and any other selective run through the scalar
+  event interpreter.  ``run_scalar`` runs the scalar interpreter for
+  forced and selective runs alike: it is the reference the two fast
+  paths are pinned against (tests/test_cold_path.py,
+  tests/test_compiled_path.py, and ``tests/golden_reports.json``, which
+  the seed engine produced);
 - **batched cold runs**: forced (recording/reference) executions sample
   every kernel, so the cold interpreter pre-splits the event program into
   *segments* bounded by RNG-consuming communication events and draws each
@@ -77,16 +83,8 @@ from .ops import (CS_BLOCK, CS_COLL, CS_COMP, CS_IMATCH, CS_IPOST, CS_P2P,
                   EV_BLOCK, EV_COLL, EV_COMP, EV_IMATCH, EV_IPOST, EV_P2P,
                   KIND_COLL, KIND_COMP, KIND_ISEND, KIND_RECV, KIND_SEND,
                   KIND_WAIT)
-from .program import (CompBlock, ColdProgram, EventProgram, ProgramCache,
-                      WarmProgram, build_cold, build_warm, compile_events)
-
-# compiled-program containers and lowering passes live in .program (PR 10:
-# programs are serializable, cacheable artifacts); the historical private
-# names stay importable for the bench/test harnesses
-_CompBlock = CompBlock
-_EventProgram = EventProgram
-_WarmProgram = WarmProgram
-_ColdProgram = ColdProgram
+from .program import (ColdProgram, EventProgram, ProgramCache, build_cold,
+                      build_warm, compile_events)
 
 RUNNABLE, BLOCKED, DONE = 0, 1, 2
 
@@ -121,23 +119,16 @@ class Runtime:
     def __init__(self, world: World, critter: Critter,
                  timer: Callable[[Signature, np.random.Generator], float],
                  *, seed: int = 0, overhead: float = 1e-6,
-                 trace_cache: bool = True, compiled: bool = True,
                  program_cache: Optional[ProgramCache] = None):
         self.world = world
         self.critter = critter
         self.timer = timer
         self.overhead = overhead
-        self.trace_cache = trace_cache
         # cross-Runtime event-program cache (see .program): consulted for
         # factories that carry a ``program_key`` structural fingerprint;
         # a hit materializes the recorded program into this World and
         # skips ``_record`` entirely
         self.program_cache = program_cache
-        # compiled selective replay (Critter.run_warm over the segmented
-        # warm program).  Bit-identical to the plain event interpreter;
-        # ``compiled=False`` forces the scalar warm path (the bench harness
-        # measures the compiled speedup against it)
-        self.compiled = compiled
         self._rng = np.random.default_rng(seed)
         self._intern = world.interner.intern
         self._sig_cache: Dict[tuple, int] = {}
@@ -202,16 +193,10 @@ class Runtime:
 
         Matching is independent of sampled times, so the recorded program
         replayed through the interpreters produces interceptions (and RNG
-        consumption) bit-identical to the historical interleaved pass.  A
-        deadlock or collective mismatch therefore raises before any
-        profiler state is touched.
-
-        KEEP IN SYNC with ``_run_live``: both implement the same
-        structural matching semantics (collective site validation, p2p
-        queues, heap sweeps, deadlock reporting); this copy exists so the
-        recording pass pays zero interception branches per op.  Any
-        change to matching must land in both; tests/test_cold_path.py and
-        tests/test_golden_reports.py pin their equivalence."""
+        consumption) bit-identical to the seed engine's interleaved pass
+        (pinned by tests/golden_reports.json).  A deadlock or collective
+        mismatch therefore raises before any profiler state is touched.
+        This is the engine's one copy of the matching semantics."""
         world = self.world
         n = world.size
         gens = [program_factory(r, world) for r in range(n)]
@@ -361,32 +346,24 @@ class Runtime:
         return events
 
     # -- event-program compilation --------------------------------------------
+    #
+    # the lowering passes live in .program: pure functions of the event
+    # list and the World's interner table
 
-    # the lowering passes live in .program (they are pure functions of the
-    # event list + the World's interner table); these wrappers keep the
-    # historical call sites — and the bench/test harnesses poking them —
-    # working unchanged
-    _compile_events = staticmethod(compile_events)
-
-    def _build_cold(self, prog: EventProgram) -> ColdProgram:
-        return build_cold(prog, self.world.interner.sigs)
-
-    def _build_warm(self, prog: EventProgram) -> WarmProgram:
-        return build_warm(prog, self.world.interner.sigs)
+    def _warm(self, prog: EventProgram):
+        if prog.warm is None:
+            prog.warm = build_warm(prog, self.world.interner.sigs)
+        return prog.warm
 
     def warm_meta(self, program_factory) -> dict:
         """Segmentation statistics of the compiled warm program for
         ``program_factory`` (recording + compiling it if needed) — consumed
         by the bench harness and the CI engine gate."""
-        prog = self._get_program(program_factory)
-        warm = prog.warm
-        if warm is None:
-            warm = prog.warm = self._build_warm(prog)
-        return dict(warm.meta)
+        return dict(self._warm(self._get_program(program_factory)).meta)
 
     # -- interpreters ---------------------------------------------------------
 
-    def _run_events(self, prog: _EventProgram, sampler) -> None:
+    def _run_events(self, prog: EventProgram, sampler) -> None:
         """Execute a compiled event program: the scheduler, matching queues
         and generators are gone; only the interception sequence remains."""
         critter = self.critter
@@ -418,15 +395,15 @@ class Runtime:
             else:
                 on_coll(ev[1], ev[2], sampler, overhead)
 
-    def _run_events_cold(self, cold: _ColdProgram) -> None:
+    def _run_events_cold(self, cold: ColdProgram) -> None:
         """Execute a cold program under force_execute.
 
         When the cost model batches, every sample of the run — computation
         AND communication — is drawn up front in one vectorized call and
         each step consumes its precomputed time at a running cursor;
         otherwise each sampling step draws through the scalar timer at its
-        own position, which is the same call sequence as the interleaved
-        seed engine.  All interceptions go through the force-specialized
+        own position, which is the same call sequence as ``run_scalar``.
+        All interceptions go through the force-specialized
         ``*_cold`` Critter methods, which operate on list-backed per-rank
         scalar mirrors for the duration of the run (``begin_cold`` ..
         ``finish_cold``) — NumPy scalar indexing dominates the p2p-heavy
@@ -518,31 +495,41 @@ class Runtime:
 
     def run(self, program_factory, *, force_execute: bool = False,
             update_stats: bool = True) -> RunResult:
+        """One iteration on the fastest path the run admits: the batched
+        cold interpreter when forced, the compiled warm replay when the
+        policy is warm-eligible, else the scalar event interpreter."""
         critter = self.critter
         critter.begin_iteration(force_execute=force_execute,
                                 update_stats=update_stats)
-        rng = self._rng
-        timer = self.timer
-        sampler = lambda sig: timer(sig, rng)  # noqa: E731
-
-        if not self.trace_cache:
-            self._run_live(program_factory, sampler)
-            return RunResult.from_report(critter.report())
-
         prog = self._get_program(program_factory)
         if force_execute:
             cold = prog.cold
             if cold is None:
-                cold = prog.cold = self._build_cold(prog)
+                cold = prog.cold = build_cold(prog, self.world.interner.sigs)
             self._run_events_cold(cold)
-        elif self.compiled and critter.warm_eligible():
-            warm = prog.warm
-            if warm is None:
-                warm = prog.warm = self._build_warm(prog)
-            critter.run_warm(warm, sampler, self.overhead)
+        elif critter.warm_eligible():
+            critter.run_warm(self._warm(prog), self._sampler(), self.overhead)
         else:
-            self._run_events(prog, sampler)
+            self._run_events(prog, self._sampler())
         return RunResult.from_report(critter.report())
+
+    def run_scalar(self, program_factory, *, force_execute: bool = False,
+                   update_stats: bool = True) -> RunResult:
+        """The reference iteration: the same recorded program through the
+        scalar event interpreter and Critter's scalar interceptions, one
+        timer draw per sampled event, for forced and selective runs alike.
+        ``run`` must match it bit for bit: reports, engine state and the
+        sampler RNG stream."""
+        critter = self.critter
+        critter.begin_iteration(force_execute=force_execute,
+                                update_stats=update_stats)
+        self._run_events(self._get_program(program_factory), self._sampler())
+        return RunResult.from_report(critter.report())
+
+    def _sampler(self):
+        rng = self._rng
+        timer = self.timer
+        return lambda sig: timer(sig, rng)
 
     def _get_program(self, program_factory) -> EventProgram:
         # fingerprint-keyed path: factories stamped with a structural
@@ -597,178 +584,3 @@ class Runtime:
         skip ``_record`` entirely.  The program must have been materialized
         into (or recorded in) THIS Runtime's World — sids are World-local."""
         self._keyed[key] = prog
-
-    def _run_live(self, program_factory, sampler) -> None:
-        """The seed engine's interleaved pass (``trace_cache=False``):
-        generators, structural matching, and scalar Critter interception in
-        one loop, nothing recorded.  Kept for programs whose op streams are
-        nondeterministic or feedback-dependent — and as the reference
-        implementation the recorded paths are pinned against
-        (tests/test_cold_path.py, tests/test_golden_reports.py).
-
-        KEEP IN SYNC with ``_record``: same structural matching semantics,
-        see the note there."""
-        world = self.world
-        critter = self.critter
-        overhead = self.overhead
-        n = world.size
-        gens = [program_factory(r, world) for r in range(n)]
-        isend_slots = [0]
-        status = [RUNNABLE] * n
-        blocked_on: List[Optional[object]] = [None] * n
-        # collective sites: (comm.id, site_index) -> _CollSite
-        coll_sites: Dict[Tuple[int, int], _CollSite] = {}
-        coll_counts: Dict[Tuple[int, int], int] = {}
-        # p2p queues: (src, dst, tag) -> deque of entries
-        # send entry: (sender_rank, sig_id, vote, snapshot_or_None, slot)
-        sends: Dict[tuple, deque] = {}
-        recvs: Dict[tuple, deque] = {}
-        next_handle = [0]
-        # runnable queue: (sweep, rank) min-heap reproducing the seed
-        # engine's sorted round-robin sweeps exactly
-        heap: List[Tuple[int, int]] = [(0, r) for r in range(n)]
-
-        live = [n]
-
-        def advance(r, sweep, value=None):
-            """Run rank r until it blocks or finishes."""
-            gen = gens[r]
-            while True:
-                try:
-                    op = gen.send(value)
-                except StopIteration:
-                    status[r] = DONE
-                    live[0] -= 1
-                    return
-                value = None
-                k = op.KIND
-                if k == KIND_COMP:
-                    sid = op.sig_id
-                    if sid is None:
-                        sid = op.sig_id = self._comp_sid(op.name, op.params)
-                    critter.on_comp(r, sid, sampler)
-                    continue
-                if k == KIND_COLL:
-                    comm = op.comm
-                    key = (comm.id, r)
-                    idx = coll_counts.get(key, 0)
-                    coll_counts[key] = idx + 1
-                    skey = (comm.id, idx)
-                    site = coll_sites.get(skey)
-                    if site is None:
-                        sid = op.sig_id
-                        if sid is None:
-                            sid = op.sig_id = \
-                                self._coll_sid(op.op, comm, op.nbytes)
-                        site = _CollSite(op.op, op.nbytes, comm.size, sid)
-                        coll_sites[skey] = site
-                    elif site.op != op.op:
-                        raise RuntimeError(
-                            f"collective mismatch on comm {comm.id} site {idx}:"
-                            f" {site.op} vs {op.op} (rank {r})")
-                    elif site.nbytes != op.nbytes:
-                        raise RuntimeError(
-                            f"collective byte-count mismatch on comm "
-                            f"{comm.id} site {idx} ({site.op}): "
-                            f"{site.nbytes}B vs {op.nbytes}B (rank {r})")
-                    site.arrived.append(r)
-                    if len(site.arrived) < site.needed:
-                        status[r] = BLOCKED
-                        blocked_on[r] = op
-                        return
-                    # complete the collective
-                    del coll_sites[skey]
-                    critter.on_coll(site.sig_id, comm, sampler, overhead)
-                    for rr in site.arrived:
-                        if rr != r:
-                            status[rr] = RUNNABLE
-                            blocked_on[rr] = None
-                            heappush(heap,
-                                     (sweep if rr > r else sweep + 1, rr))
-                    continue
-                if k == KIND_SEND:
-                    sid = op.sig_id
-                    if sid is None:
-                        sid = op.sig_id = self._p2p_sid("send", op.nbytes)
-                    pkey = (r, op.dst, op.tag)
-                    q = recvs.get(pkey)
-                    if q:
-                        q.popleft()
-                        vote = critter.p2p_vote(r, sid)
-                        critter.on_p2p(r, op.dst, sid, sampler, vote,
-                                       overhead)
-                        dst = op.dst
-                        status[dst] = RUNNABLE
-                        blocked_on[dst] = None
-                        heappush(heap,
-                                 (sweep if dst > r else sweep + 1, dst))
-                        continue
-                    sends.setdefault(pkey, deque()).append(
-                        (r, sid, None, None, 0))
-                    status[r] = BLOCKED
-                    blocked_on[r] = op
-                    return
-                if k == KIND_RECV:
-                    pkey = (op.src, r, op.tag)
-                    q = sends.get(pkey)
-                    if q:
-                        src, sid, vote, snapshot, slot = q.popleft()
-                        if snapshot is None:   # blocking sender, rendezvous
-                            vote = critter.p2p_vote(src, sid)
-                            critter.on_p2p(src, r, sid, sampler, vote,
-                                           overhead)
-                            status[src] = RUNNABLE
-                            blocked_on[src] = None
-                            heappush(heap,
-                                     (sweep if src > r else sweep + 1, src))
-                        else:                  # buffered isend
-                            critter.on_isend_match(src, r, sid, sampler,
-                                                   vote, snapshot, overhead)
-                        continue
-                    recvs.setdefault(pkey, deque()).append(r)
-                    status[r] = BLOCKED
-                    blocked_on[r] = op
-                    return
-                if k == KIND_ISEND:
-                    sid = op.sig_id
-                    if sid is None:
-                        sid = op.sig_id = self._p2p_sid("send", op.nbytes)
-                    slot = isend_slots[0]
-                    isend_slots[0] = slot + 1
-                    vote = critter.p2p_vote(r, sid)
-                    snapshot = critter.isend_snapshot(r)
-                    pkey = (r, op.dst, op.tag)
-                    q = recvs.get(pkey)
-                    if q:
-                        rcv = q.popleft()
-                        critter.on_isend_match(r, rcv, sid, sampler, vote,
-                                               snapshot, overhead)
-                        status[rcv] = RUNNABLE
-                        blocked_on[rcv] = None
-                        heappush(heap,
-                                 (sweep if rcv > r else sweep + 1, rcv))
-                    else:
-                        sends.setdefault(pkey, deque()).append(
-                            (r, sid, vote, snapshot, slot))
-                    next_handle[0] += 1
-                    value = next_handle[0]
-                    continue
-                if k == KIND_WAIT:
-                    # buffered isend: completion is free; the interception
-                    # point exists but statistics were updated at match time
-                    continue
-                raise TypeError(f"rank {r} yielded unknown op {op!r}")
-
-        while heap:
-            sweep, r = heappop(heap)
-            if status[r] == RUNNABLE:
-                advance(r, sweep)
-        if live[0] > 0:
-            blocked = [(r, blocked_on[r]) for r in range(n)
-                       if status[r] == BLOCKED]
-            if blocked:
-                detail = ", ".join(f"rank {r}: {op!r}"
-                                   for r, op in blocked[:8])
-                raise DeadlockError(
-                    f"{len(blocked)} ranks blocked with no progress: "
-                    f"{detail}")

@@ -25,7 +25,6 @@ from repro.api import (AutotuneSession, ConfigPoint, ConfigRecord,
                        WallClockBackend)
 from repro.core.policies import POLICIES
 from repro.core.signatures import comp_sig
-from repro.core.tuner import space_of_study
 from repro.simmpi.costmodel import CostModel, KNL_STAMPEDE2
 
 from golden_runner import GOLDEN_PATH, _studies
@@ -51,15 +50,14 @@ def _strip_wall(result_json: dict) -> dict:
 def test_session_simbackend_reproduces_goldens():
     with open(GOLDEN_PATH) as f:
         golden = json.load(f)
-    for study in _studies():
-        space = space_of_study(study)
+    for space in _studies():
         for pol in POLICIES:
             session = AutotuneSession(space, backend=_golden_backend(),
                                       policy=pol, tolerance=0.25, trials=2)
             result = session.run()
-            assert result.study == study.name
+            assert result.study == space.name
             assert result.backend == "sim"
-            g_recs = golden[study.name][pol]
+            g_recs = golden[space.name][pol]
             assert len(result.records) == len(g_recs)
             got = json.loads(json.dumps(
                 [r.to_json() for r in result.records]))
@@ -67,15 +65,14 @@ def test_session_simbackend_reproduces_goldens():
                 assert n["name"] == g["name"]
                 for field in GOLDEN_FIELDS:
                     assert n[field] == g[field], \
-                        f"{study.name}/{pol}/{g['name']}/{field}: " \
+                        f"{space.name}/{pol}/{g['name']}/{field}: " \
                         f"{n[field]!r} != {g[field]!r}"
 
 
 # -- parallel sweep determinism ----------------------------------------------
 
 def _tiny_session():
-    study = _studies()[1]           # golden-capital: world 8, 2 configs
-    return AutotuneSession(space_of_study(study),
+    return AutotuneSession(_studies()[1],   # golden-capital: world 8
                            backend=_golden_backend(), trials=2)
 
 
@@ -84,7 +81,7 @@ def test_parallel_sweep_matches_serial():
     # on a single-core CI box a cpu-derived count degenerates to 1 and the
     # fork path silently goes untested.  run_tasks forks regardless of
     # core count, so 2 workers exercise it everywhere fork exists.
-    from repro.api.parallel import fork_available
+    from repro.api.scheduler import fork_available
     assert fork_available(), \
         "no os.fork: the parallel sweep path cannot be exercised here"
     kw = dict(policies=["conditional", "eager"], tolerances=[1.0, 0.25])
@@ -141,8 +138,7 @@ def test_study_checkpoint_resumes_partial_records(tmp_path):
     uninterrupted one — including the sim RNG stream, which the journal
     carries across the interruption."""
     ck = str(tmp_path / "study.json")
-    study = _studies()[0]           # golden-slate: resets between configs
-    space = space_of_study(study)
+    space = _studies()[0]           # golden-slate: resets between configs
 
     def session(backend):
         return AutotuneSession(space, backend=backend, policy="online",
@@ -166,8 +162,7 @@ def test_study_checkpoint_resumes_partial_records(tmp_path):
 # -- racing through the session front-end -------------------------------------
 
 def test_racing_search_via_session():
-    study = _studies()[1]
-    session = AutotuneSession(space_of_study(study),
+    session = AutotuneSession(_studies()[1],
                               backend=_golden_backend(), policy="online",
                               tolerance=0.25, search="racing",
                               search_options={"max_rounds": 3})
@@ -242,9 +237,8 @@ _XPROC_SNIPPET = """
 import json, sys
 sys.path.insert(0, sys.argv[1]); sys.path.insert(0, sys.argv[2])
 from repro.api import AutotuneSession, SimBackend
-from repro.core.tuner import space_of_study
 from golden_runner import _studies
-res = AutotuneSession(space_of_study(_studies()[1]), backend=SimBackend(),
+res = AutotuneSession(_studies()[1], backend=SimBackend(),
                       policy="online", tolerance=0.25, trials=2).run()
 d = res.to_json(); d.pop("wall_s")
 print(json.dumps(d, sort_keys=True))
@@ -305,9 +299,6 @@ def test_study_result_json_roundtrip_lossless():
     back = StudyResult.from_json(json.loads(json.dumps(res.to_json())))
     assert back == res
     assert back.records[0].params["b"] == (2, 3)
-    # StudyReport is the same class: the legacy name round-trips too
-    from repro.core.tuner import StudyReport
-    assert StudyReport is StudyResult
 
 
 def test_serializer_rejects_unknown_types():

@@ -4,8 +4,9 @@ The PR-4 cold path splits the first (recording/forced) execution into a
 structural recording pass plus a batched interpreter that pre-draws kernel
 samples (vectorized when the cost model's straggler branch is off, scalar
 fallback when it is on) and charges fused computation runs in bulk.  These
-tests pin it to the scalar reference — ``trace_cache=False`` runs the
-seed-style interleaved pass — requiring bit-identical:
+tests pin it to the scalar reference — ``Runtime.run_scalar`` runs the same
+recorded program through the scalar interceptions, one timer draw per
+sampled event — requiring bit-identical:
 
 - iteration reports (every ``IterationReport`` field),
 - engine state after every iteration (statistics, mean mirrors, counts,
@@ -60,23 +61,25 @@ def _state_snapshot(critter):
                    for sid, st in S.kbar[r].items()))
 
 
-def _run_protocol(study, pol, straggler_p, trace_cache):
+def _run_protocol(study, pol, straggler_p, scalar):
     """The tuner's per-configuration pattern: forced reference run, three
     selective trials, then a forced ``update_stats=False`` replay (the
-    next configuration's reference measurement)."""
+    next configuration's reference measurement); through ``run_scalar``
+    when ``scalar``, else through ``run``."""
     world_size, make = STUDIES[study]
     w = World(world_size)
     c = Critter(w, policy(pol, tolerance=0.25))
     cm = CostModel(KNL_STAMPEDE2, allocation=0, seed=0,
                    straggler_p=straggler_p)
-    rt = Runtime(w, c, cm.sample, seed=3, trace_cache=trace_cache)
+    rt = Runtime(w, c, cm.sample, seed=3)
+    run = rt.run_scalar if scalar else rt.run
     prog = make(w)
     trace = []
     for i in range(4):
-        res = rt.run(prog, force_execute=(i == 0))
+        res = run(prog, force_execute=(i == 0))
         trace.append(tuple(getattr(res, f) for f in REPORT_FIELDS))
         trace.append(_state_snapshot(c))
-    res = rt.run(prog, force_execute=True, update_stats=False)
+    res = run(prog, force_execute=True, update_stats=False)
     trace.append(tuple(getattr(res, f) for f in REPORT_FIELDS))
     trace.append(_state_snapshot(c))
     trace.append(rt._rng.bit_generator.state)
@@ -88,8 +91,8 @@ def _run_protocol(study, pol, straggler_p, trace_cache):
 @pytest.mark.parametrize("straggler_p", [0.002, 0.0],
                          ids=["straggler-on", "straggler-off"])
 def test_cold_path_bit_identical(study, pol, straggler_p):
-    scalar = _run_protocol(study, pol, straggler_p, trace_cache=False)
-    batched = _run_protocol(study, pol, straggler_p, trace_cache=True)
+    scalar = _run_protocol(study, pol, straggler_p, scalar=True)
+    batched = _run_protocol(study, pol, straggler_p, scalar=False)
     for i, (a, b) in enumerate(zip(scalar, batched)):
         assert a == b, (f"{study}/{pol}/straggler={straggler_p}: "
                         f"divergence at trace step {i}")
@@ -101,22 +104,22 @@ def test_rng_stream_batched_vs_scalar():
     vectorized pre-draw (straggler off) and the scalar fallback."""
     for straggler_p in (0.0, 0.002):
         states = []
-        for trace_cache in (False, True):
+        for scalar in (True, False):
             w = World(16)
             c = Critter(w, policy("online", tolerance=0.25))
             cm = CostModel(KNL_STAMPEDE2, allocation=0, seed=0,
                            straggler_p=straggler_p)
-            rt = Runtime(w, c, cm.sample, seed=11,
-                         trace_cache=trace_cache)
-            rt.run(STUDIES["slate"][1](w), force_execute=True)
+            rt = Runtime(w, c, cm.sample, seed=11)
+            run = rt.run_scalar if scalar else rt.run
+            run(STUDIES["slate"][1](w), force_execute=True)
             states.append(rt._rng.bit_generator.state)
         assert states[0] == states[1], f"straggler_p={straggler_p}"
 
 
 def test_bench_engine_verify_cold_path():
     """The bench_engine assertion wired into check.sh: batched and
-    unbatched cold runs record identical event programs and produce
-    bit-identical reports/RNG streams."""
+    unbatched cold runs record identical event programs, and the batched
+    run matches ``run_scalar`` in reports and RNG stream."""
     from benchmarks.bench_engine import verify_cold_path
     summary = verify_cold_path(16)
     assert summary["report"]["skipped"] == 0   # forced run executes all
@@ -132,16 +135,17 @@ def test_custom_timer_falls_back_to_scalar_draws():
         calls.append(sig.kind)
         return 0.5 + 0.25 * rng.random()
 
-    def run(trace_cache):
+    def run(scalar):
         calls.clear()
         w = World(8)
         c = Critter(w, policy("conditional", tolerance=0.25))
-        rt = Runtime(w, c, timer, seed=5, trace_cache=trace_cache)
-        res = rt.run(STUDIES["capital"][1](w), force_execute=True)
+        rt = Runtime(w, c, timer, seed=5)
+        run = rt.run_scalar if scalar else rt.run
+        res = run(STUDIES["capital"][1](w), force_execute=True)
         return ([getattr(res, f) for f in REPORT_FIELDS], list(calls),
                 rt._rng.bit_generator.state)
 
-    assert run(False) == run(True)
+    assert run(True) == run(False)
 
 
 def test_update_many_matches_sequential_updates():

@@ -6,18 +6,17 @@ communication boundaries become head entries that batch-charge the whole
 segment when every kernel in it is in the memoized-skip regime, and the
 straggler-enabled cost model adopts a counter-based (Philox-style) RNG
 discipline so mixed normal/uniform draws batch per segment.  These tests
-pin the compiled path (``compiled=True``, the default for trace-cached
-selective runs) to the scalar event-program interpreter
-(``compiled=False``) and the seed-style live engine
-(``trace_cache=False``), requiring bit-identical reports, engine state
-and RNG streams — plus segment-boundary edge cases the SLATE/Capital/
-CANDMC studies don't produce on their own (comm-only programs, segments
-of a single event, skip decisions flipping mid-program).
+pin the compiled path (what ``Runtime.run`` takes for warm-eligible
+selective runs) to the scalar reference ``Runtime.run_scalar``,
+requiring bit-identical reports, engine state and RNG streams — plus
+segment-boundary edge cases the SLATE/Capital/CANDMC studies don't
+produce on their own (comm-only programs, segments of a single event,
+skip decisions flipping mid-program).
 
 The full 5-policies x 3-studies x straggler matrix already runs the
-compiled path implicitly in tests/test_cold_path.py (compiled is the
-default); here the matrix is compiled-vs-scalar-interpreter, which
-isolates the warm-program lowering from the recording pass.
+compiled path implicitly in tests/test_cold_path.py, which also pins the
+forced runs; the matrix here is the SLATE study's, plus the edge cases
+that probe the warm-program lowering.
 """
 
 from __future__ import annotations
@@ -57,10 +56,11 @@ def _state_snapshot(critter):
                    for sid, st in S.kbar[r].items()))
 
 
-def _trace(make, world_size, pol, *, straggler_p=0.0, compiled=True,
-           trace_cache=True, counter_rng=False, iters=3, timer=None):
+def _trace(make, world_size, pol, *, straggler_p=0.0, scalar=False,
+           counter_rng=False, iters=3, timer=None):
     """Forced run + ``iters`` selective iterations; per-iteration reports
-    and state fingerprints plus the final RNG stream position."""
+    and state fingerprints plus the final RNG stream position, every
+    iteration through ``run_scalar`` when ``scalar``, else ``run``."""
     w = World(world_size)
     c = Critter(w, policy(pol, tolerance=0.25))
     if timer is None:
@@ -70,12 +70,12 @@ def _trace(make, world_size, pol, *, straggler_p=0.0, compiled=True,
     else:
         cm = None
         sample = timer
-    rt = Runtime(w, c, sample, seed=3, trace_cache=trace_cache,
-                 compiled=compiled)
+    rt = Runtime(w, c, sample, seed=3)
     prog = make(w)
     out = []
+    run = rt.run_scalar if scalar else rt.run
     for i in range(1 + iters):
-        res = rt.run(prog, force_execute=(i == 0))
+        res = run(prog, force_execute=(i == 0))
         out.append(tuple(getattr(res, f) for f in REPORT_FIELDS))
         out.append(_state_snapshot(c))
     out.append(cm.draw_index if counter_rng else
@@ -94,18 +94,17 @@ def _assert_traces_equal(a, b, label):
 @pytest.mark.parametrize("straggler_p", [0.002, 0.0],
                          ids=["straggler-on", "straggler-off"])
 def test_compiled_matches_scalar_interpreter(pol, straggler_p):
-    comp, _, _ = _trace(_slate, 16, pol, straggler_p=straggler_p,
-                        compiled=True)
+    comp, _, _ = _trace(_slate, 16, pol, straggler_p=straggler_p)
     scal, _, _ = _trace(_slate, 16, pol, straggler_p=straggler_p,
-                        compiled=False)
+                        scalar=True)
     _assert_traces_equal(comp, scal, f"{pol}/straggler={straggler_p}")
 
 
 def test_compiled_is_the_default_selective_path():
-    """``compiled=True`` (the default) must actually build and run the
-    warm program on selective iterations; ``compiled=False`` must not."""
-    _, rt_c, prog_c = _trace(_slate, 16, "online", compiled=True)
-    _, rt_s, prog_s = _trace(_slate, 16, "online", compiled=False)
+    """``run`` must actually build and run the warm program on selective
+    iterations; ``run_scalar`` must not."""
+    _, rt_c, prog_c = _trace(_slate, 16, "online")
+    _, rt_s, prog_s = _trace(_slate, 16, "online", scalar=True)
     assert rt_c._traces[prog_c].warm is not None
     assert rt_s._traces[prog_s].warm is None
 
@@ -126,8 +125,8 @@ def test_comm_only_program_has_no_segments():
     """A program with no computation never opens a comp run: the warm
     program degenerates to per-event entries (zero segments) and still
     matches the scalar interpreter bit-for-bit."""
-    comp, rt, prog = _trace(_comm_only, 8, "online", compiled=True)
-    scal, _, _ = _trace(_comm_only, 8, "online", compiled=False)
+    comp, rt, prog = _trace(_comm_only, 8, "online")
+    scal, _, _ = _trace(_comm_only, 8, "online", scalar=True)
     _assert_traces_equal(comp, scal, "comm-only")
     meta = rt.warm_meta(prog)
     assert meta["segments"] == 0 and meta["fused_events"] == 0
@@ -148,10 +147,8 @@ def test_single_event_segments_never_fuse():
     """A comp run of one event gets no head entry (nothing to batch), so
     the warm program carries it as a plain W_COMP — and the charge is
     identical either way."""
-    comp, rt, prog = _trace(_single_event_segments, 4, "online",
-                            compiled=True)
-    scal, _, _ = _trace(_single_event_segments, 4, "online",
-                        compiled=False)
+    comp, rt, prog = _trace(_single_event_segments, 4, "online")
+    scal, _, _ = _trace(_single_event_segments, 4, "online", scalar=True)
     _assert_traces_equal(comp, scal, "single-event segments")
     meta = rt.warm_meta(prog)
     assert meta["segments"] == 0 and meta["fused_events"] == 0
@@ -190,14 +187,11 @@ def test_skip_decision_flips_mid_program():
             return 1e-3 * (1.0 + 0.01 * rng.normal())
         return 1e-4
 
-    comp, rt, prog = _trace(_flip_prone, 4, "online", compiled=True,
-                            iters=5, timer=noisy_timer)
-    scal, _, _ = _trace(_flip_prone, 4, "online", compiled=False,
-                        iters=5, timer=noisy_timer)
-    live, _, _ = _trace(_flip_prone, 4, "online", trace_cache=False,
-                        iters=5, timer=noisy_timer)
-    _assert_traces_equal(comp, scal, "flip-prone vs interpreter")
-    _assert_traces_equal(comp, live, "flip-prone vs live")
+    comp, rt, prog = _trace(_flip_prone, 4, "online", iters=5,
+                            timer=noisy_timer)
+    scal, _, _ = _trace(_flip_prone, 4, "online", scalar=True, iters=5,
+                        timer=noisy_timer)
+    _assert_traces_equal(comp, scal, "flip-prone vs scalar reference")
     meta = rt.warm_meta(prog)
     assert meta["segments"] > 0                        # fusion did happen
     # the last selective iteration really did mix skips and executions
@@ -210,12 +204,9 @@ def test_eager_aggregation_inside_segments():
     """The eager policy re-aggregates global statistics at collectives —
     mid-replay, between segments.  The compiled path must observe the
     refreshed global skip set exactly as the scalar engine does."""
-    comp, _, _ = _trace(_flip_prone, 4, "eager", compiled=True, iters=5)
-    scal, _, _ = _trace(_flip_prone, 4, "eager", compiled=False, iters=5)
-    live, _, _ = _trace(_flip_prone, 4, "eager", trace_cache=False,
-                        iters=5)
-    _assert_traces_equal(comp, scal, "eager vs interpreter")
-    _assert_traces_equal(comp, live, "eager vs live")
+    comp, _, _ = _trace(_flip_prone, 4, "eager", iters=5)
+    scal, _, _ = _trace(_flip_prone, 4, "eager", scalar=True, iters=5)
+    _assert_traces_equal(comp, scal, "eager vs scalar reference")
 
 
 # ------------------------------------------------------- counter-RNG path
@@ -250,31 +241,32 @@ def test_counter_mode_disables_legacy_batching():
 @pytest.mark.parametrize("pol", ["online", "eager"])
 def test_counter_rng_cold_and_warm_bit_identical(pol):
     """With stragglers ON and counter mode, the batched cold path and the
-    compiled warm path must match the live engine — including the draw
+    compiled warm path must match ``run_scalar`` — including the draw
     cursor, the counter-mode analogue of the bit-generator state (this is
     the PR-5 residual: the straggler cold path used to fall back to
     per-event scalar draws; now it batches through sample_block)."""
     cached, _, _ = _trace(_slate, 16, pol, straggler_p=0.002,
-                          counter_rng=True, trace_cache=True)
-    live, _, _ = _trace(_slate, 16, pol, straggler_p=0.002,
-                        counter_rng=True, trace_cache=False)
-    _assert_traces_equal(cached, live, f"counter/{pol}")
-    assert cached[-1] == live[-1] > 0       # draw cursors advanced, equal
+                          counter_rng=True)
+    scal, _, _ = _trace(_slate, 16, pol, straggler_p=0.002,
+                        counter_rng=True, scalar=True)
+    _assert_traces_equal(cached, scal, f"counter/{pol}")
+    assert cached[-1] == scal[-1] > 0       # draw cursors advanced, equal
 
 
 def test_counter_cold_cursor_matches_live():
     """The recording (forced) run in counter mode pre-draws through
     sample_block — one bulk cursor advance that must land exactly where
-    the per-event live pass leaves its cursor (3 counter slots per drawn
-    sample, whether the straggler branch fires or not)."""
+    ``run_scalar``'s per-event draws leave its cursor (3 counter slots per
+    drawn sample, whether the straggler branch fires or not)."""
     cursors = []
-    for trace_cache in (True, False):
+    for scalar in (False, True):
         w = World(16)
         c = Critter(w, policy("online", tolerance=0.25))
         cm = CostModel(KNL_STAMPEDE2, allocation=0, seed=0,
                        straggler_p=0.002, counter_rng=True)
-        rt = Runtime(w, c, cm.sample, seed=3, trace_cache=trace_cache)
-        rt.run(_slate(w), force_execute=True)
+        rt = Runtime(w, c, cm.sample, seed=3)
+        run = rt.run_scalar if scalar else rt.run
+        run(_slate(w), force_execute=True)
         cursors.append(cm.draw_index)
     assert cursors[0] == cursors[1] > 0
     assert cursors[0] % 3 == 0

@@ -45,9 +45,9 @@ def test_reports_bit_identical_to_seed_engine():
 
 
 def test_trace_replay_is_deterministic():
-    """The same configuration run through the recorded event program (runs
-    2+) must behave identically to a fresh engine running the generators
-    every time (trace_cache=False)."""
+    """The same configuration run through the fast paths (the batched cold
+    interpreter, then the compiled warm replay) must behave identically
+    to the scalar reference ``run_scalar`` on every iteration."""
     from repro.core.critter import Critter
     from repro.core.policies import policy
     from repro.linalg import slate_cholesky
@@ -55,22 +55,23 @@ def test_trace_replay_is_deterministic():
     from repro.simmpi.costmodel import CostModel, KNL_STAMPEDE2
     from repro.simmpi.runtime import Runtime
 
-    def run_seq(trace_cache):
+    def run_seq(scalar):
         w = World(16)
         c = Critter(w, policy("online", tolerance=0.25))
         cm = CostModel(KNL_STAMPEDE2, seed=3, bias_sigma=0.0)
-        rt = Runtime(w, c, cm.sample, seed=3, trace_cache=trace_cache)
+        rt = Runtime(w, c, cm.sample, seed=3)
+        run = rt.run_scalar if scalar else rt.run
         prog = slate_cholesky.make_program(w, n=512, tile=64, lookahead=1,
                                            pr=4, pc=4)
         out = []
         for i in range(4):
-            res = rt.run(prog, force_execute=(i == 0))
+            res = run(prog, force_execute=(i == 0))
             out.append((res.predicted_time, res.wall_time, res.crit_comp,
                         res.crit_comm, res.measured_time, res.executed,
                         res.skipped))
         return out
 
-    assert run_seq(True) == run_seq(False)
+    assert run_seq(False) == run_seq(True)
 
 
 # -- interned signature ids round-trip to equal Signatures -------------------

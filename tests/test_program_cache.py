@@ -28,8 +28,8 @@ from repro.linalg import candmc_qr, capital_cholesky, slate_cholesky
 from repro.simmpi.comm import World
 from repro.simmpi.costmodel import CostModel, KNL_STAMPEDE2
 from repro.simmpi.program import (PROGRAM_VERSION, ProgramCache,
-                                  program_from_payload, program_to_payload,
-                                  structural_fingerprint)
+                                  compile_events, program_from_payload,
+                                  program_to_payload, structural_fingerprint)
 from repro.simmpi.runtime import Runtime
 
 REPORT_FIELDS = ("predicted_time", "wall_time", "crit_comp", "crit_comm",
@@ -143,7 +143,7 @@ def test_payload_round_trip_equivalence():
     rt = Runtime(w1, Critter(w1, policy("eager", 0.25)),
                  CostModel(KNL_STAMPEDE2).sample)
     before = len(w1._comms)
-    prog = rt._compile_events(rt._record(make(w1)))
+    prog = compile_events(rt._record(make(w1)))
     comms = list(w1._comms)[before:]
     payload = program_to_payload(prog, w1.interner.sigs, comms)
     payload = json.loads(json.dumps(payload))        # full JSON round trip
@@ -252,7 +252,7 @@ def _hammer(args):
     rt = Runtime(w, Critter(w, policy("conditional", 0.25)),
                  CostModel(KNL_STAMPEDE2).sample)
     before = len(w._comms)
-    prog = rt._compile_events(rt._record(make(w)))
+    prog = compile_events(rt._record(make(w)))
     comms = list(w._comms)[before:]
     fp = FP["capital"]
     cache = ProgramCache(path)

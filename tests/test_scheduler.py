@@ -36,7 +36,6 @@ from repro.api import (AutotuneSession, RemoteExecutor, Scheduler,
 from repro.api.scheduler import (DONE, FAILED, ForkExecutor,
                                  InProcessExecutor, fork_available)
 from repro.core.policies import POLICIES
-from repro.core.tuner import space_of_study
 from repro.simmpi.costmodel import CostModel, KNL_STAMPEDE2
 
 from golden_runner import GOLDEN_PATH, _studies, golden_space
@@ -52,7 +51,7 @@ def _golden_backend():
 
 
 def _capital_session(backend=None, **kw):
-    return AutotuneSession(space_of_study(_studies()[1]),
+    return AutotuneSession(_studies()[1],
                            backend=backend or _golden_backend(),
                            trials=2, **kw)
 
@@ -586,7 +585,7 @@ def test_kill_and_resume_restores_shared_prior(tmp_path):
 # -- racing through the scheduler ----------------------------------------------
 
 def test_scheduler_drives_racing_sweeps():
-    session = AutotuneSession(space_of_study(_studies()[1]),
+    session = AutotuneSession(_studies()[1],
                               backend=_golden_backend(), search="racing",
                               trials=1, search_options={"max_rounds": 3})
     kw = dict(policies=["online", "conditional"], tolerances=[0.25])
@@ -598,16 +597,16 @@ def test_scheduler_drives_racing_sweeps():
         assert r.extra["best"] in names
     if fork_available():
         forked = AutotuneSession(
-            space_of_study(_studies()[1]), backend=_golden_backend(),
+            _studies()[1], backend=_golden_backend(),
             search="racing", trials=1,
             search_options={"max_rounds": 3}).sweep(workers=2, **kw)
         assert [_strip(r) for r in forked] == [_strip(r) for r in serial]
 
 
-# -- run_tasks compat shim -----------------------------------------------------
+# -- run_tasks: the plain fork pool ------------------------------------------
 
 def test_run_tasks_shim_preserves_contract():
-    from repro.api.parallel import run_tasks
+    from repro.api.scheduler import run_tasks
     landed = []
     out = run_tasks([1, 2, 3], lambda t: {"t": t}, workers=2,
                     on_result=lambda i, r: landed.append((i, r)))

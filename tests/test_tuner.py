@@ -1,27 +1,26 @@
-"""Autotuner behaviour on the paper's case studies (CI scale, fast subsets)."""
+"""Tuner behaviour on the paper's case studies (CI scale, fast subsets),
+through ``AutotuneSession`` over a ``SimBackend``."""
 
 import numpy as np
 import pytest
 
+from repro.api import AutotuneSession, SimBackend
 from repro.core.policies import policy
-from repro.core.tuner import Autotuner, Configuration, Study
-from repro.linalg import capital_cholesky
 from repro.linalg.studies import capital_cholesky_study
 from repro.simmpi.costmodel import CostModel, KNL_STAMPEDE2
 
 
 def tiny_capital_study(n_configs=4):
-    full = capital_cholesky_study("ci")
-    return Study(name="tiny-capital", world_size=full.world_size,
-                 configs=full.configs[:n_configs],
-                 reset_between_configs=False, machine=full.machine)
+    return capital_cholesky_study("ci").subset(n_configs)
+
+
+def _tune(space, pol, **kw):
+    return AutotuneSession(space, SimBackend(), policy=pol, **kw).run()
 
 
 def test_exhaustive_tuner_speedup_and_optimum():
-    study = tiny_capital_study()
-    tuner = Autotuner(study, policy("eager", tolerance=0.3), trials=3,
-                      seed=0)
-    rep = tuner.tune()
+    rep = _tune(tiny_capital_study(), policy("eager", tolerance=0.3),
+                trials=3, seed=0)
     assert rep.speedup > 1.5
     assert rep.optimum_quality >= 0.95
     assert all(r.rel_error < 0.6 for r in rep.records)
@@ -30,34 +29,29 @@ def test_exhaustive_tuner_speedup_and_optimum():
 def test_error_decreases_with_tolerance():
     errs = {}
     for tol in (1.0, 0.05):
-        study = tiny_capital_study()
-        tuner = Autotuner(study, policy("online", tolerance=tol),
-                          trials=3, seed=1)
-        rep = tuner.tune()
+        rep = _tune(tiny_capital_study(), policy("online", tolerance=tol),
+                    trials=3, seed=1)
         errs[tol] = rep.mean_error
     assert errs[0.05] <= errs[1.0] + 0.02
 
 
 def test_apriori_charges_offline_pass():
-    study = tiny_capital_study(2)
-    t_apriori = Autotuner(study, policy("apriori", tolerance=0.3),
-                          trials=2, seed=0).tune()
-    study2 = tiny_capital_study(2)
-    t_cond = Autotuner(study2, policy("conditional", tolerance=0.3),
-                       trials=2, seed=0).tune()
+    t_apriori = _tune(tiny_capital_study(2),
+                      policy("apriori", tolerance=0.3), trials=2, seed=0)
+    t_cond = _tune(tiny_capital_study(2),
+                   policy("conditional", tolerance=0.3), trials=2, seed=0)
     # the offline pass is charged to apriori's autotuning time
     assert t_apriori.selective_tuning_time > \
         0.9 * t_cond.selective_tuning_time
 
 
 def test_racing_prunes_and_finds_optimum():
-    study = tiny_capital_study()
-    tuner = Autotuner(study, policy("online", tolerance=0.3), trials=1,
-                      seed=0)
-    rep = tuner.tune_racing(max_rounds=6)
+    space = tiny_capital_study()
+    rep = _tune(space, policy("online", tolerance=0.3), trials=1, seed=0,
+                search="racing", search_options={"max_rounds": 6})
     # racing must not benchmark every config every round
-    assert rep.total_iterations < 6 * len(study.configs)
-    assert rep.best in {c.name for c in study.configs}
+    assert rep.extra["total_iterations"] < 6 * len(space)
+    assert rep.extra["best"] in {p.name for p in space.points}
 
 
 def test_extrapolate_policy_skips_more():
@@ -67,14 +61,10 @@ def test_extrapolate_policy_skips_more():
 
     reps = {}
     for extra in (False, True):
-        full = candmc_qr_study("ci")
-        study = Study(name="candmc-sub", world_size=full.world_size,
-                      configs=full.configs[:3], reset_between_configs=True,
-                      machine=full.machine)
-        rep = Autotuner(study, policy("online", tolerance=0.3,
-                                      extrapolate=extra),
-                        trials=2, seed=0).tune()
-        reps[extra] = rep
+        reps[extra] = _tune(candmc_qr_study("ci").subset(3),
+                            policy("online", tolerance=0.3,
+                                   extrapolate=extra),
+                            trials=2, seed=0)
     assert reps[True].optimum_quality >= 0.99
     sel = {k: r.selective_tuning_time for k, r in reps.items()}
     assert sel[True] <= sel[False] * 1.05
